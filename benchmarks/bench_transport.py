@@ -20,7 +20,7 @@ Per (corpus entry, engine) the sweep measures:
     sees);
   * **projected interconnect-bound wall time** — the measured HLO bytes
     fed through the same roofline cost model the tuner ranks with
-    (bytes / ICI_BW + per-tick dispatch + local FLOPs at the compacted
+    (bytes / ICI bandwidth + per-tick dispatch + local FLOPs at the compacted
     backend's occupancy): the transport PR's headline — >= 1.3x over
     the dense path on at least one low-occupancy corpus entry — is
     asserted on this projection, with the measured byte ratio as its
@@ -56,7 +56,7 @@ from repro.core.commvolume import plan_volume  # noqa: E402
 from repro.core.engine import lower_multiply, multiply  # noqa: E402
 from repro.core.local_mm import backend_local_cost  # noqa: E402
 from repro.launch.mesh import make_spgemm_mesh  # noqa: E402
-from repro.roofline import ICI_BW, PEAK_FLOPS  # noqa: E402
+from repro.roofline import TARGET_PEAKS  # noqa: E402
 from repro.roofline.hlo_cost import analyze_hlo  # noqa: E402
 from repro.tuner.corpus import CorpusEntry  # noqa: E402
 from repro.tuner.features import featurize  # noqa: E402
@@ -123,7 +123,8 @@ def projected_s(bytes_on_wire: float, plan, feats, ndev: int) -> float:
         feats.bs_r, feats.bs_k, feats.bs_c,
         fill=feats.product_fill, backend="stacks",
     )
-    return bytes_on_wire / ICI_BW + local / ndev / PEAK_FLOPS
+    return (bytes_on_wire / TARGET_PEAKS.ici_bw
+            + local / ndev / TARGET_PEAKS.flops)
 
 
 def bench_entry(entry: CorpusEntry, mesh, engine: str, reps: int) -> dict:

@@ -1,8 +1,10 @@
 """Measured collective bytes of the actual TPU engines (lowered HLO).
 
-Standalone (sets the fake-device flag before importing jax — run as
-``python benchmarks/measure_comm.py`` or via benchmarks.run which spawns it
-as a subprocess so the main process keeps seeing one device).
+A check of the compiled HLO on 64 fake CPU host devices, not a chip
+measurement.  Standalone (sets the fake-device flag before importing jax —
+run as ``JAX_PLATFORMS=cpu python benchmarks/measure_comm.py``, or via
+benchmarks.run, which spawns it as a subprocess with ``JAX_PLATFORMS=cpu``
+so that it never contends for an accelerator its parent holds).
 
 Measures, per engine x mesh, the per-device collective wire bytes of one
 block-sparse multiplication, and validates the paper's claims on the real

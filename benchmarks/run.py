@@ -94,7 +94,8 @@ def summarize_bench_json(paths: list[str] | None = None) -> int:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--skip-measured", action="store_true",
-                    help="skip the 64-fake-device HLO measurement subprocess")
+                    help="skip the 64-fake-device HLO measurement subprocess "
+                    "(it runs on the CPU: JAX_PLATFORMS=cpu)")
     ap.add_argument("--only", nargs="*", default=None)
     ap.add_argument("--summary-only", action="store_true",
                     help="only aggregate existing BENCH_*.json files")
@@ -118,11 +119,16 @@ def main() -> None:
             print(f"{name}/CHECK_FAILED,-1,{e!r}", flush=True)
 
     if not args.skip_measured and (not args.only or "measured" in args.only):
-        # HLO-measured engine collective bytes need fake devices -> subprocess
+        # HLO-measured engine collective bytes: a check of the compiled
+        # HLO on 64 fake CPU devices, so a subprocess (the flag must precede
+        # its jax import) pinned to the CPU with JAX_PLATFORMS=cpu — this
+        # process has imported jax and may hold the accelerator, which a
+        # child must never contend for
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         proc = subprocess.run(
             [sys.executable, os.path.join(root, "benchmarks", "measure_comm.py")],
             capture_output=True, text=True, timeout=900,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         if proc.returncode != 0:
             failures.append(("measured", proc.stderr[-500:]))

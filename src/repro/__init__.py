@@ -5,10 +5,10 @@ import jax as _jax
 
 __version__ = "1.0.0"
 
-# Sharding-invariant PRNG: partitionable threefry is the default from
-# jax 0.5; on 0.4.x the default (False) makes `jax.random` draws depend on
-# the out_sharding, which breaks layout-equivalence guarantees this repo
-# relies on (ZeRO-1 init == replicated init, cross-mesh checkpoint
-# restore).  Version-compat shims for APIs live in ``repro.compat``.
+# Sharding-invariant PRNG: without partitionable threefry, `jax.random`
+# draws depend on the out_sharding, which breaks layout-equivalence
+# guarantees this repo relies on (ZeRO-1 init == replicated init,
+# cross-mesh checkpoint restore).  It is the default from jax 0.5; the
+# update only guards against a config that turned it off.
 if not _jax.config.jax_threefry_partitionable:
     _jax.config.update("jax_threefry_partitionable", True)

@@ -335,9 +335,10 @@ def pallas_interpret() -> bool | None:
     """Configured Pallas interpret mode, or None for platform auto-detect.
 
     ``REPRO_PALLAS_INTERPRET`` overrides: "1"/"true" forces the
-    interpreter (debugging on any platform), "0"/"false" forces compiled
+    interpreter (debugging off the TPU), "0"/"false" forces compiled
     Mosaic kernels, unset/"auto" lets the wrappers pick — interpret off on
-    real TPU, on elsewhere (``repro.kernels.ops._default_interpret``).
+    real TPU, on elsewhere (``repro.kernels.ops._default_interpret``, which
+    refuses a forced interpreter on a TPU).
     """
     import os
 

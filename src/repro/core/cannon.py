@@ -33,10 +33,9 @@ equality of Table 2; compressed transport scales both by panel occupancy.
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pcast, shard_map
 from repro.core import transport as T
 from repro.core.bsm import BlockSparseMatrix
 from repro.core.local_mm import local_filtered_mm
@@ -89,8 +88,8 @@ def ring_body(
             (ab.shape[0], bb.shape[1], ab.shape[2], bb.shape[3]), ab.dtype
         )
         cm = jnp.zeros((ab.shape[0], bb.shape[1]), bool)
-        cb = pcast(cb, axes, to="varying")
-        cm = pcast(cm, axes, to="varying")
+        cb = lax.pcast(cb, axes, to="varying")
+        cm = lax.pcast(cm, axes, to="varying")
 
         if ticks == 1:
             return compute(pa, pb, cb, cm)

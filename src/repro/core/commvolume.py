@@ -263,7 +263,8 @@ def device_memory_bytes(
       gathered row/column panels;
     * the compacted-backend stack arrays when ``stack_capacity`` > 0:
       gathered A/B operands, the product buffer (f32) and the seven
-      int32 index arrays of ``kernels.stacks.ProductStacks``.
+      int32 index arrays of ``kernels.stacks.ProductStacks``, laid out as
+      the platform pads them (``local_mm.stack_entry_bytes``).
 
     The tuner prunes every candidate whose footprint exceeds the
     per-device budget — the one decision the measured trials must never
@@ -314,9 +315,9 @@ def device_memory_bytes(
     else:
         raise ValueError(plan.kind)
     if stack_capacity > 0:
-        # gathered a, b + f32 product per entry
-        gemm = (bs * bs_k + bs_k * bs_c + bs * bs_c) * 4.0
-        total += stack_capacity * (gemm + 7 * 4.0)
+        from repro.core.local_mm import stack_entry_bytes
+
+        total += stack_capacity * stack_entry_bytes(bs, bs_k, bs_c)
     return total
 
 

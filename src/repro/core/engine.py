@@ -50,9 +50,10 @@ from repro.core.bsm import (
 )
 from repro.core.local_mm import (
     GATHER_OVERHEAD,
-    backend_local_cost,
+    choose_local_backend,
     local_filtered_mm,
 )
+from repro.kernels.stacks import bucket_capacity
 
 ENGINES = ("cannon", "onesided", "gather", "twofive")
 
@@ -78,13 +79,16 @@ def choose_backend(a: BlockSparseMatrix, b: BlockSparseMatrix,
                    threshold: float = 0.0, *, ok=None) -> str:
     """Cost-model-driven local-backend selection (the ``"auto"`` policy).
 
-    Delegates to the shared analytic model
-    (``local_mm.backend_local_cost``, also used by the tuner's candidate
-    ranking — DESIGN.md §6): dense einsum when the full-cube MXU work
-    undercuts the compacted path's gathered products, compacted list
-    otherwise; the compacted flavor is the Pallas kernel on real TPU and
-    the jnp gather-GEMM-scatter elsewhere.  Traced inputs (inside someone
-    else's jit) fall back to ``jnp`` — no concrete pattern to compact.
+    Delegates to ``local_mm.choose_local_backend`` (the shared analytic
+    model, also used by the tuner — DESIGN.md §6) at the exact bucketed
+    capacity: dense einsum when the full-cube MXU work undercuts the
+    compacted path's gathered products, or when the ``stacks`` list
+    would not fit the device; compacted list otherwise, in
+    ``local_mm.compacted_backend``'s flavor (the Pallas kernel on a TPU
+    where the block shape has a lane-aligned tile, the XLA
+    gather-GEMM-scatter elsewhere).  Traced inputs (inside
+    someone else's jit) fall back to ``jnp`` — no concrete pattern to
+    compact.
 
     ``ok`` — optional precomputed concrete filter cube, so one host walk
     serves both this heuristic and the capacity bound in ``multiply``.
@@ -94,16 +98,10 @@ def choose_backend(a: BlockSparseMatrix, b: BlockSparseMatrix,
             return "jnp"
         ok = _host_pair_filter(a, b, threshold)
     fill = float(ok.mean()) if ok.size else 0.0
-    ni, nk = a.nb_r, a.nb_c
-    nj = b.nb_c
-    dims = (ni, nk, nj, a.bs_r, a.bs_c, b.bs_c)
-    dense = backend_local_cost(*dims, fill=1.0, backend="jnp",
-                               dtype=a.dtype)
-    compact = backend_local_cost(*dims, fill=fill, backend="stacks",
-                                 dtype=a.dtype)
-    if dense <= compact:
-        return "jnp"
-    return "pallas" if jax.default_backend() == "tpu" else "stacks"
+    return choose_local_backend(
+        a.nb_r, a.nb_c, b.nb_c, a.bs_r, a.bs_c, b.bs_c, fill, a.dtype,
+        capacity=bucket_capacity(int(ok.sum())),
+    )
 
 
 # distributed per-device capacity bounds live in the plan layer

@@ -21,9 +21,9 @@ predicted-volume model).
 """
 from __future__ import annotations
 
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import transport as T
 from repro.core.bsm import BlockSparseMatrix
 from repro.core.local_mm import local_filtered_mm

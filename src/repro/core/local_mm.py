@@ -32,6 +32,8 @@ caller (after the cross-device reduction, where applicable).
 """
 from __future__ import annotations
 
+import math
+import os
 from dataclasses import dataclass
 
 import jax
@@ -39,10 +41,12 @@ import jax.numpy as jnp
 
 from repro.kernels.block_spgemm import (
     VMEM_BUDGET_BYTES,
+    tile_candidates,
     tile_working_set_bytes,
 )
 from repro.kernels.stacks import (
     ProductStacks,
+    bucket_capacity,
     compact_pair_mask,
     resolve_capacity,
 )
@@ -60,9 +64,14 @@ GATHER_OVERHEAD = 4.0
 # doubles, 8-bit quadruples on hardware that packs the systolic array).
 _MXU_DTYPE_SPEEDUP = {4: 1.0, 2: 2.0, 1: 4.0}
 
-# FLOP-equivalents of one HBM byte (PEAK_FLOPS / HBM_BW for TPU v5e-class
-# parts, 197e12 / 819e9 — kept inline to avoid a roofline import cycle).
-_FLOPS_PER_BYTE = 240.0
+
+def _flops_per_byte() -> float:
+    """FLOP-equivalents of one HBM byte on this device (peak FLOP/s over
+    HBM bytes/s, from ``roofline.device_peaks``)."""
+    from repro.roofline import device_peaks
+
+    peaks = device_peaks()
+    return peaks.flops / peaks.hbm_bw
 
 
 @dataclass(frozen=True)
@@ -145,8 +154,8 @@ def local_stage_cost(
         return LocalCost(flops, hbm, float("inf"), feasible=False)
     if ws > VMEM_BUDGET_BYTES / 2:
         # double buffering lost: the full traffic joins the critical path
-        return LocalCost(flops, hbm, compute + hbm * _FLOPS_PER_BYTE)
-    return LocalCost(flops, hbm, compute + extra * _FLOPS_PER_BYTE)
+        return LocalCost(flops, hbm, compute + hbm * _flops_per_byte())
+    return LocalCost(flops, hbm, compute + extra * _flops_per_byte())
 
 
 def backend_local_cost(
@@ -167,6 +176,99 @@ def backend_local_cost(
         ni, nk, nj, bs_r, bs_k, bs_c, fill=fill, backend=backend,
         dtype=dtype, tile=tile,
     ).effective
+
+
+def device_memory_budget() -> float:
+    """Per-device byte budget for memory-bound choices: the device's HBM
+    (``roofline.device_peaks``) with a 10% reserve.
+    ``REPRO_DEVICE_MEMORY_BYTES`` overrides it for tests."""
+    from repro.roofline import device_peaks
+
+    raw = os.environ.get("REPRO_DEVICE_MEMORY_BYTES", "").strip()
+    return float(raw) if raw else 0.9 * device_peaks().hbm_bytes
+
+
+def stack_entry_bytes(bs_r: int, bs_k: int, bs_c: int) -> float:
+    """Device bytes per product-list entry of the ``stacks`` path: the
+    gathered A and B blocks and the product, all f32, plus the int32
+    arrays of ``ProductStacks``.  On a TPU the minor two dims of each
+    (capacity, rows, cols) f32 array are laid out in (8, 128) tiles, so a
+    23 x 23 block takes 24 x 128 words, 5.8x its size (the compiled
+    program's ``memory_analysis`` agrees: ``tests/test_chip_compile.py``).
+    """
+    def words(rows: int, cols: int) -> int:
+        if jax.default_backend() == "tpu":
+            rows, cols = -(-rows // 8) * 8, -(-cols // 128) * 128
+        return rows * cols
+
+    return 4.0 * (words(bs_r, bs_k) + words(bs_k, bs_c) + words(bs_r, bs_c)
+                  + len(ProductStacks._fields))
+
+
+def stacks_memory_bytes(
+    ni: int, nk: int, nj: int, bs_r: int, bs_k: int, bs_c: int,
+    capacity: int, dtype=jnp.float32,
+) -> float:
+    """Device footprint of one ``stacks`` local stage: the A, B and C
+    blocks at storage width plus ``capacity`` stack entries."""
+    itemsize = jnp.dtype(dtype).itemsize
+    operands = (ni * nk * bs_r * bs_k + nk * nj * bs_k * bs_c
+                + ni * nj * bs_r * bs_c) * itemsize
+    return operands + capacity * stack_entry_bytes(bs_r, bs_k, bs_c)
+
+
+def choose_local_backend(
+    ni: int, nk: int, nj: int,
+    bs_r: int, bs_k: int, bs_c: int,
+    fill: float,
+    dtype=jnp.float32,
+    capacity: int | None = None,
+) -> str:
+    """The ``"auto"`` local backend of one multiply: ``"jnp"`` or
+    ``compacted_backend``'s choice.
+
+    The dense einsum wins where its full-cube MXU work undercuts the
+    compacted path's gathered products (``backend_local_cost``).  A
+    ``"stacks"`` pick must also fit the device: where its footprint
+    (``stacks_memory_bytes`` at ``capacity`` entries, else the bucketed
+    ``fill`` of the cube) exceeds ``device_memory_budget``, the choice is
+    ``"jnp"``, which never materialises the product list.  Shared by
+    ``engine.choose_backend``, the tuner and envelope chains.
+    """
+    dense = backend_local_cost(ni, nk, nj, bs_r, bs_k, bs_c,
+                               fill=1.0, backend="jnp", dtype=dtype)
+    compact = backend_local_cost(ni, nk, nj, bs_r, bs_k, bs_c,
+                                 fill=fill, backend="stacks", dtype=dtype)
+    if dense <= compact:
+        return "jnp"
+    backend = compacted_backend(bs_r, bs_k, bs_c, dtype)
+    if capacity is None:
+        capacity = bucket_capacity(math.ceil(fill * ni * nk * nj))
+    if backend == "stacks" and stacks_memory_bytes(
+        ni, nk, nj, bs_r, bs_k, bs_c, capacity, dtype
+    ) > device_memory_budget():
+        return "jnp"
+    return backend
+
+
+def compacted_backend(bs_r: int, bs_k: int, bs_c: int, dtype=jnp.float32) -> str:
+    """The compacted local backend for one block shape on this platform.
+
+    The rule: ``"pallas"`` only on a TPU, and only where the block shape
+    has a (tm, tk, tn) tile that compiled Mosaic accepts
+    (``validate_tile(..., interpret=False)``: tk and tn multiples of 128,
+    tm a multiple of the dtype's sublane count).  Everywhere else
+    ``"stacks"``, the XLA gather / batched GEMM / segment-sum path, which
+    on a TPU runs on the same chip.  DBCSR's atomic blocks of 23, 6 and 32
+    have no such tile, so on a TPU they take ``"stacks"``.  The one policy
+    point of ``choose_local_backend`` and the tuner's candidate
+    enumeration.
+    """
+    if jax.default_backend() == "tpu" and tile_candidates(
+        bs_r, bs_k, bs_c, dtype, interpret=False
+    ):
+        return "pallas"
+    return "stacks"
 
 
 def pair_filter(

@@ -223,11 +223,11 @@ def get_sweep_program(
         if backend == "auto":
             # the envelope's union cube is the chain-wide fill bound:
             # feed it through the same analytic crossover the tuner uses
-            from repro.tuner.model import choose_local_backend
+            from repro.core.local_mm import choose_local_backend
 
             backend = choose_local_backend(
                 x.nb_r, x.nb_c, x.nb_c, x.bs_r, x.bs_c, x.bs_c,
-                fill=float(envelope.cube.mean()),
+                fill=float(envelope.cube.mean()), dtype=x.dtype,
             )
         if stack_capacity is None and backend in ("stacks", "pallas"):
             stack_capacity = (
@@ -290,9 +290,8 @@ def get_sweep_program(
 
             return jax.jit(_make_sweep(mm, x.dtype, filter_eps,
                                        total_blocks=total_blocks))
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from repro.compat import shard_map
 
         plan = plan_mod.plan_multiply(mesh, engine, l)
         plan.validate_blocks(x.nb_r, x.nb_c)

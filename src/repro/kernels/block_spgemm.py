@@ -62,6 +62,11 @@ MAX_TILE = 256
 # class hardware).  Above half of it, Pallas can no longer double-buffer.
 VMEM_BUDGET_BYTES = 16 * 2**20
 
+# Products per kernel launch.  Each one scalar-prefetches 16 bytes of
+# indices and control words into SMEM (1 MiB per core); 2^15 of them take
+# half of it.  Longer product lists run as several launches.
+MAX_PREFETCH_PRODUCTS = 2**15
+
 
 def min_sublane(dtype) -> int:
     """Minimum sublane multiple of a VMEM tile for this storage dtype."""
@@ -180,16 +185,15 @@ def tile_candidates(
 ) -> list[tuple[int, int, int] | None]:
     """Distinct tile shapes worth measuring for one block shape.
 
-    ``None`` (the default_tile resolution) always leads; explicit
-    candidates cover the whole block, the MXU edge, and the default
-    ceiling — deduplicated and filtered through ``validate_tile``.  In
+    ``None`` (the default_tile resolution) leads; explicit candidates
+    cover the whole block, the MXU edge, and the default ceiling —
+    deduplicated and, like ``None``, kept only where ``validate_tile``
+    accepts them.  In compiled mode a block shape with no lane-aligned
+    tile (DBCSR's atomic 23, 6 or 32) therefore gets an empty list.  In
     interpret mode half-block tiles join so CPU tests/benchmarks exercise
     a real tile grid at small sizes.
     """
-    raw: list[tuple[int, int, int]] = [
-        (bs_r, bs_k, bs_c),
-        default_tile(bs_r, bs_k, bs_c, dtype),
-    ]
+    raw: list[tuple[int, int, int]] = [(bs_r, bs_k, bs_c)]
     sl = min_sublane(dtype)
     for cap in (LANE, MAX_TILE):
         raw.append((
@@ -200,47 +204,80 @@ def tile_candidates(
     if interpret:
         if bs_r % 2 == 0 and bs_k % 2 == 0 and bs_c % 2 == 0:
             raw.append((bs_r // 2, bs_k // 2, bs_c // 2))
-    out: list[tuple[int, int, int] | None] = [None]
-    seen = {default_tile(bs_r, bs_k, bs_c, dtype)}  # what None resolves to
-    for t in raw:
-        if t in seen:
-            continue
+    default = default_tile(bs_r, bs_k, bs_c, dtype)
+    out: list[tuple[int, int, int] | None] = []
+    for t in dict.fromkeys([default, *raw]):  # default first, deduplicated
         try:
             validate_tile(bs_r, bs_k, bs_c, t, dtype, interpret=interpret)
         except ValueError:
             continue
-        seen.add(t)
-        out.append(t)
+        out.append(None if t == default else t)
     return out
 
 
-def _tiled_kernel(
-    ia_ref, ik_ref, ij_ref, tile_ref, first_ref, write_ref, valid_ref,
-    a_ref, b_ref, c_ref, acc_ref,
-):
+# Flag bits of the packed per-product control word (``_chunk_flags``).
+_RESET, _WRITE, _VALID, _LOAD = 1, 2, 4, 8
+
+
+def _chunk_flags(stacks: ProductStacks, start: int, stop: int) -> jax.Array:
+    """Control words of products ``[start, stop)`` as one grid launch sees them.
+
+    RESET — zero the accumulator (first product of an output tile's k-run);
+    WRITE — write the accumulator back (last product of the run, or the
+    launch's last step, which leaves a partial sum in C); VALID — a real
+    product; LOAD — seed the accumulator from C (the launch opens in the
+    middle of a k-run that an earlier launch started).
+    """
+    first = stacks.first[start:stop]
+    write = stacks.write[start:stop].at[-1].set(1)
+    load = jnp.zeros_like(first).at[0].set(1 - first[0])
+    return (first * _RESET + write * _WRITE + stacks.valid[start:stop] * _VALID
+            + load * _LOAD)
+
+
+def _mxu_dot(a, b):
+    """One tile product with f32 accumulation. f32 operands multiply at
+    full f32 precision (Mosaic's default may use fewer bf16 passes);
+    narrower storage multiplies natively in bf16, whose products are exact
+    in f32."""
+    if a.dtype == jnp.float32:
+        return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
+    return jnp.dot(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+
+
+def _tiled_kernel(ia_ref, ik_ref, ij_ref, flag_ref, a_ref, b_ref, *refs):
+    # refs = ([c_in_ref,] c_ref, acc_ref): c_in (aliased to c) is present
+    # only when the product list spans several launches
+    c_ref, acc_ref = refs[-2:]
     n = pl.program_id(2)
     tk = pl.program_id(3)
     ntk = pl.num_programs(3)
+    flags = flag_ref[n]
 
-    @pl.when((first_ref[n] == 1) & (tk == 0))
+    @pl.when(((flags & _RESET) != 0) & (tk == 0))
     def _reset():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(valid_ref[n] == 1)
-    def _mac():
-        acc_ref[...] += jnp.dot(
-            a_ref[0, 0].astype(jnp.float32),
-            b_ref[0, 0].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
+    if len(refs) == 3:
+        c_in_ref = refs[0]
 
-    @pl.when((write_ref[n] == 1) & (tk == ntk - 1))
+        @pl.when(((flags & _LOAD) != 0) & (tk == 0))
+        def _load():
+            acc_ref[...] = c_in_ref[0, 0].astype(jnp.float32)
+
+    @pl.when((flags & _VALID) != 0)
+    def _mac():
+        acc_ref[...] += _mxu_dot(a_ref[0, 0], b_ref[0, 0])
+
+    @pl.when(((flags & _WRITE) != 0) & (tk == ntk - 1))
     def _write():
         c_ref[0, 0] = acc_ref[...].astype(c_ref.dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("ni", "nj", "tile", "interpret")
+    jax.jit, static_argnames=("ni", "nj", "tile", "interpret", "chunk")
 )
 def block_spgemm_stacks(
     a_blocks: jax.Array,  # (ni, nk, bs_r, bs_k)
@@ -251,6 +288,7 @@ def block_spgemm_stacks(
     nj: int,
     tile: tuple[int, int, int] | None = None,
     interpret: bool = False,
+    chunk: int | None = None,
 ) -> jax.Array:
     """C tiles of the compacted product list over the (tm, tk, tn) grid.
 
@@ -258,6 +296,13 @@ def block_spgemm_stacks(
     callers zero the rest via the tile mask (``jnp.any(pair_ok, axis=1)``),
     exactly the ``c_mask`` they already compute.  ``tile=None`` resolves
     ``default_tile`` (whole-block for blocks up to ``MAX_TILE`` per dim).
+
+    The product list's index and control words are scalar-prefetched into
+    SMEM, 16 bytes per product.  A list longer than ``chunk`` (default
+    ``MAX_PREFETCH_PRODUCTS``) runs as several launches of at most
+    ``chunk`` products over one f32 C buffer that each launch aliases: a
+    k-run cut by a launch boundary is written back as a partial sum and
+    re-loaded by the next launch, so accumulation is unchanged.
     """
     from jax.experimental.pallas import tpu as pltpu
 
@@ -266,54 +311,69 @@ def block_spgemm_stacks(
     assert bs_k == bs_k2, (a_blocks.shape, b_blocks.shape)
     assert nj2 == nj, (nj2, nj)
     dtype = a_blocks.dtype
-    out = jax.ShapeDtypeStruct((ni, nj, bs_r, bs_c), dtype)
     cap = stacks.capacity
     if cap == 0:
-        return jnp.zeros(out.shape, out.dtype)
+        return jnp.zeros((ni, nj, bs_r, bs_c), dtype)
     if tile is None:
         tile = default_tile(bs_r, bs_k, bs_c, dtype)
     tm, tk, tn = validate_tile(
         bs_r, bs_k, bs_c, tile, dtype, interpret=interpret
     )
     n_tm, n_tk, n_tn = bs_r // tm, bs_k // tk, bs_c // tn
+    chunk = min(cap, chunk or MAX_PREFETCH_PRODUCTS)
+    multi = cap > chunk
+    # several launches accumulate partial sums in f32 between them
+    c_dtype = jnp.float32 if multi else dtype
 
     # Output sub-tile coordinates outermost, contraction tiles innermost:
     # for one (ti, tj) the whole product list streams past the single
     # (tm, tn) accumulator, so k-run fusion is preserved per sub-tile.
     # Index maps receive (grid idx..., *scalar prefetch refs).
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(n_tm, n_tn, cap, n_tk),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, tm, tk),
-                lambda ti, tj, n, tkk, ia, ik, ij, *_: (
-                    ia[n], ik[n], ti, tkk
-                ),
-            ),
-            pl.BlockSpec(
-                (1, 1, tk, tn),
-                lambda ti, tj, n, tkk, ia, ik, ij, *_: (
-                    ik[n], ij[n], tkk, tj
-                ),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, tm, tn),
-            lambda ti, tj, n, tkk, ia, ik, ij, *_: (ia[n], ij[n], ti, tj),
+    def c_map(ti, tj, n, tkk, ia, ik, ij, *_):
+        return ia[n], ij[n], ti, tj
+
+    c_spec = pl.BlockSpec((1, 1, tm, tn), c_map)
+    in_specs = [
+        pl.BlockSpec(
+            (1, 1, tm, tk),
+            lambda ti, tj, n, tkk, ia, ik, ij, *_: (ia[n], ik[n], ti, tkk),
         ),
-        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-    )
-    return pl.pallas_call(
-        _tiled_kernel,
-        grid_spec=grid_spec,
-        out_shape=out,
-        interpret=interpret,
-    )(*stacks, a_blocks, b_blocks)
+        pl.BlockSpec(
+            (1, 1, tk, tn),
+            lambda ti, tj, n, tkk, ia, ik, ij, *_: (ik[n], ij[n], tkk, tj),
+        ),
+    ]
+    out_shape = jax.ShapeDtypeStruct((ni, nj, bs_r, bs_c), c_dtype)
+
+    def launch(length):
+        return pl.pallas_call(
+            _tiled_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(n_tm, n_tn, length, n_tk),
+                in_specs=in_specs + [c_spec] if multi else in_specs,
+                out_specs=c_spec,
+                scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+            ),
+            out_shape=out_shape,
+            # operand 6 (after 4 prefetch words, A and B) is C itself
+            input_output_aliases={6: 0} if multi else {},
+            interpret=interpret,
+        )
+
+    c = jnp.zeros(out_shape.shape, c_dtype)
+    for start in range(0, cap, chunk):
+        stop = min(start + chunk, cap)
+        c = launch(stop - start)(
+            stacks.ia[start:stop], stacks.ik[start:stop],
+            stacks.ij[start:stop], _chunk_flags(stacks, start, stop),
+            a_blocks, b_blocks, *((c,) if multi else ()),
+        )
+    return c.astype(dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("capacity", "tile", "interpret")
+    jax.jit, static_argnames=("capacity", "tile", "interpret", "chunk")
 )
 def block_spgemm(
     a_blocks: jax.Array,  # (ni, nk, bs_r, bs_k)
@@ -323,6 +383,7 @@ def block_spgemm(
     capacity: int | None = None,
     tile: tuple[int, int, int] | None = None,
     interpret: bool = False,
+    chunk: int | None = None,
 ) -> jax.Array:
     """C_ij = sum_k ok[i,k,j] * A_ik @ B_kj via the compacted product list.
 
@@ -330,7 +391,8 @@ def block_spgemm(
     full cube — always sound, no compaction win; callers with a concrete
     pattern pass the exact bucketed count (``plan.get_product_stacks``) so
     grid steps and DMA traffic shrink to the survivors.  ``tile`` picks
-    the MXU sub-tile shape (None = ``default_tile``).
+    the MXU sub-tile shape (None = ``default_tile``), ``chunk`` the
+    products per launch (None = ``MAX_PREFETCH_PRODUCTS``).
     """
     ni, nk, bs_r, bs_k = a_blocks.shape
     nk2, nj, bs_k2, bs_c = b_blocks.shape
@@ -340,7 +402,7 @@ def block_spgemm(
     stacks = compact_pair_mask(pair_ok, capacity=cap)
     c = block_spgemm_stacks(
         a_blocks, b_blocks, stacks, ni=ni, nj=nj, tile=tile,
-        interpret=interpret,
+        interpret=interpret, chunk=chunk,
     )
     # tiles with no surviving product are never visited by the grid
     c_mask = jnp.any(pair_ok.astype(bool), axis=1)

@@ -4,6 +4,9 @@
 argument wins, then the ``REPRO_PALLAS_INTERPRET`` env override, then
 platform auto-detection — False (compiled Mosaic kernels) on real TPU,
 True (validation mode — the kernel body executes in Python) elsewhere.
+On a TPU the interpreter runs only where a caller passes
+``interpret=True`` itself: an environment that resolves to it there
+raises, so a timed run can never be an interpreted one.
 """
 from __future__ import annotations
 
@@ -20,10 +23,17 @@ from repro.kernels.stacks import ProductStacks  # noqa: F401  (re-export)
 
 
 def _default_interpret() -> bool:
+    on_tpu = jax.default_backend() == "tpu"
     cfg = pallas_interpret()
-    if cfg is not None:
-        return cfg
-    return jax.default_backend() != "tpu"
+    if cfg is None:
+        return not on_tpu
+    if cfg and on_tpu:
+        raise ValueError(
+            "REPRO_PALLAS_INTERPRET resolves the Pallas kernels to the "
+            "interpreter on a TPU; pass interpret=True explicitly to run "
+            "them interpreted there"
+        )
+    return cfg
 
 
 def block_spgemm(

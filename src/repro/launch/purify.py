@@ -2,7 +2,7 @@
 long-running service loop.
 
     PYTHONPATH=src python -m repro.launch.purify --nb 16 --bs 8 \
-        --p 2 --repeats 3 --sync-every 4 --tuning-db tuning_db.json
+        --repeats 3 --sync-every 4 --tuning-db tuning_db.json
 
 The production rendering of the paper's driving workload: build a sparse
 model Hamiltonian, shard it ONCE onto the SpGEMM mesh, and run repeated
@@ -21,22 +21,44 @@ file for the next launch.  Without a tuning DB the driver falls back to
 the static ``--engine`` choice (default twofive) — a production loop
 should not silently re-measure on every start.
 
-On real hardware the same driver runs on a TPU slice mesh; here the
-device count is faked for a laptop-scale proof (set
-``--devices 0`` to use the real platform devices).
+The driver runs on the platform's real devices, and the (r, c) grid
+defaults to the largest square the device count holds (``--p 1`` on one
+chip).  ``--fake-devices N`` instead fakes N CPU host devices, for
+multi-device runs on a CPU; it takes effect only in a process that has
+not initialised jax yet.
+
+``run`` returns what the purifications produced, for callers that check
+it (``chip_smoke.py``); ``main`` is the command line.
 """
 from __future__ import annotations
 
 import argparse
+import math
 import os
 import sys
+from dataclasses import dataclass
 
 
-def main(argv=None) -> int:
+@dataclass
+class PurifyRun:
+    """What :func:`run` produced: the Hamiltonian of the last repeat and
+    its density matrix (both in the mesh layout), each repeat's
+    ``SignIterStats`` and wall seconds (repeat 0 includes compiling)."""
+
+    h: object
+    p: object
+    stats: list
+    seconds: list[float]
+    mesh: object
+
+
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--nb", type=int, default=16, help="block-grid side")
     ap.add_argument("--bs", type=int, default=8, help="atomic block size")
-    ap.add_argument("--p", type=int, default=2, help="(r, c) grid side")
+    ap.add_argument("--p", type=int, default=None,
+                    help="(r, c) grid side (default: the largest square "
+                    "the devices hold)")
     ap.add_argument("--l", type=int, default=1, help="2.5D depth (l axis)")
     ap.add_argument("--engine", default="auto",
                     choices=("auto", "cannon", "onesided", "gather",
@@ -53,16 +75,22 @@ def main(argv=None) -> int:
     ap.add_argument("--tol", type=float, default=1e-6)
     ap.add_argument("--repeats", type=int, default=3,
                     help="purifications of the (perturbed) Hamiltonian")
-    ap.add_argument("--devices", type=int, default=None,
-                    help="fake host devices (default: enough for the mesh; "
-                    "0 = use the real platform devices)")
-    args = ap.parse_args(argv)
+    ap.add_argument("--fake-devices", type=int, default=None,
+                    help="fake this many CPU host devices instead of "
+                    "using the platform's devices")
+    return ap
 
-    need = args.p * args.p * max(args.l, 1)
-    if args.devices != 0:
-        fake = args.devices or need
+
+def main(argv=None) -> int:
+    run(argv)
+    return 0
+
+
+def run(argv=None) -> PurifyRun:
+    args = _parser().parse_args(argv)
+    if args.fake_devices:
         os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={fake} "
+            f"--xla_force_host_platform_device_count={args.fake_devices} "
             + os.environ.get("XLA_FLAGS", "")
         )
 
@@ -74,13 +102,16 @@ def main(argv=None) -> int:
     from repro.core import bsm as B
     from repro.core import plan as plan_mod
     from repro.core.signiter import density_matrix, trace
+    from repro.launch.cache import enable_compile_cache
     from repro.launch.mesh import make_spgemm_mesh
 
-    mesh = make_spgemm_mesh(p=args.p, l=args.l)
+    enable_compile_cache()
+    p_side = args.p or math.isqrt(len(jax.devices()) // args.l)
+    mesh = make_spgemm_mesh(p=p_side, l=args.l)
     engine = args.engine
     h = B.random_bsm(
-        jax.random.key(0), nb=args.nb, bs=args.bs, occupancy=args.occupancy,
-        pattern="decay", symmetric=True,
+        jax.random.key(0), nb=args.nb, bs=args.bs,
+        occupancy=args.occupancy, pattern="decay", symmetric=True,
     )
     mu = 0.0
     plan_mod.clear_cache()
@@ -99,7 +130,13 @@ def main(argv=None) -> int:
           + (f" (db {args.tuning_db})" if engine == "auto" else "")
           + f", sync_every {args.sync_every}")
     h_dev = B.shard_bsm(h, mesh)  # the one chain-boundary scatter
+    all_stats, seconds = [], []
     for rep in range(args.repeats):
+        if rep:
+            # SCF-like drift: perturb H on-device and re-purify (same
+            # pattern -> every cache level hits; the chain program is
+            # reused as-is)
+            h_dev = h_dev.scale(1.0 + 1e-3 * rep)
         t0 = time.perf_counter()
         p, stats = density_matrix(
             h_dev, mu, engine=engine,
@@ -107,7 +144,10 @@ def main(argv=None) -> int:
             max_iter=args.max_iter, tol=args.tol,
             mode="fused", sync_every=args.sync_every,
         )
+        jax.block_until_ready(p.blocks)
         dt = time.perf_counter() - t0
+        all_stats.append(stats)
+        seconds.append(dt)
         cache = plan_mod.cache_stats()
         sweeps_s = stats.iterations / dt if dt > 0 else float("inf")
         print(f"  repeat {rep}: {stats.iterations} sweeps "
@@ -118,9 +158,6 @@ def main(argv=None) -> int:
               f"chain {cache['chain_hits']}h/{cache['chain_misses']}m "
               f"tuner {cache['tuner_hits']}h/{cache['tuner_misses']}m/"
               f"{cache['tuner_trials']}t")
-        # SCF-like drift: perturb H on-device and re-purify (same pattern
-        # -> every cache level hits; the chain program is reused as-is)
-        h_dev = h_dev.scale(1.0 + 1e-3 * (rep + 1))
     final = plan_mod.cache_stats()
     # the chain program is compiled exactly once; program builds beyond it
     # can only come from the tuner's measured trials (cold DB), never from
@@ -135,7 +172,8 @@ def main(argv=None) -> int:
     db = tuner.get_default_db()
     if db is not None and db.path:
         print(f"tuning db: {len(db)} record(s) at {db.path}")
-    return 0
+    return PurifyRun(h=h_dev, p=p, stats=all_stats, seconds=seconds,
+                     mesh=mesh)
 
 
 if __name__ == "__main__":

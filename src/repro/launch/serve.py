@@ -30,6 +30,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.engine import GenerationConfig, ServingEngine
 
@@ -72,6 +73,7 @@ def main(argv=None) -> int:
                     help="tuning database path (created if missing); "
                          "omitted = static decisions only")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.tuning_db:
         from repro import tuner

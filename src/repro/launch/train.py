@@ -33,6 +33,7 @@ from repro.checkpoint import CheckpointManager
 from repro.config import ShapeConfig
 from repro.configs import get_arch
 from repro.data.pipeline import DataConfig, SyntheticLMData, make_global_batch
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.steps import StepOptions, build_train_step
 from repro.models import transformer as T
@@ -90,6 +91,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

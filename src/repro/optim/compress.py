@@ -22,9 +22,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 
 
 CompressState = Any  # pytree of fp32 residuals, same structure as grads

@@ -16,9 +16,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 
 
 

@@ -1,8 +1,8 @@
-"""Three-term roofline analysis from compiled XLA artifacts.
+"""Three-term roofline analysis from compiled XLA artifacts, and the
+device peaks table.
 
-CPU-only container: TPU v5e is the *target*, not the runtime, so wall-clock
-MFU cannot be measured.  Instead every dry-run cell derives, from the
-compiled SPMD module (which is the per-device program):
+Every dry-run cell derives, from the compiled SPMD module (which is the
+per-device program), a modelled lower bound — not a measured time:
 
     compute term     = HLO_FLOPs_per_device / peak_FLOP/s
     memory term      = HLO_bytes_per_device / HBM_bw
@@ -23,19 +23,62 @@ to wire bytes with the standard ring-algorithm factors:
     all-to-all        (n-1)/n * payload_bytes
     collective-permute  payload_bytes
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (one link's worth per chip is the conservative per-chip injection rate
-used for the collective term).
+The peaks come from ``DEVICE_PEAKS``, keyed by ``device_kind``
+(``device_peaks``).  A run on a TPU prices against its own chip and fails
+on a kind the table lacks; a run on any other platform prices against
+``TARGET_PEAKS``, the v5e, as a modelling target.
 """
 from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
 
-# --- TPU v5e target constants ------------------------------------------------
-PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link (per-chip injection, conservative)
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # HBM bytes/s
+    ici_bw: float  # bytes/s of one ICI link: the conservative per-chip
+    # injection rate the collective term uses
+    hbm_bytes: float  # HBM capacity
+    source: str
+
+
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9, hbm_bytes=16e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+        "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip over 4 links",
+    ),
+}
+
+# The chip that runs on no TPU price against: a modelling target, not the
+# device they run on.
+TARGET_PEAKS = DEVICE_PEAKS["TPU v5 lite"]
+
+
+def device_peaks(device=None) -> DevicePeaks:
+    """Peaks of ``device`` (default: ``jax.devices()[0]``).
+
+    A TPU is looked up by its ``device_kind`` and an unknown kind raises
+    ``KeyError``; any other platform gets ``TARGET_PEAKS``.
+    """
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return TARGET_PEAKS
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for TPU kind {device.device_kind!r}; add "
+            f"it to repro.roofline.DEVICE_PEAKS with its source"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1,
@@ -202,9 +245,9 @@ def analyze(
     *,
     n_chips: int,
     model_flops_total: float,
-    peak_flops: float = PEAK_FLOPS,
-    hbm_bw: float = HBM_BW,
-    ici_bw: float = ICI_BW,
+    peak_flops: float | None = None,
+    hbm_bw: float | None = None,
+    ici_bw: float | None = None,
     attn_tile_signature: tuple[int, int] | None = (512, 1024),
     flash_kernel_bytes: float = 0.0,
 ) -> RooflineReport:
@@ -223,8 +266,15 @@ def analyze(
     kernel-adjusted = raw - measured tile traffic + ``flash_kernel_bytes``
     (the kernel's true Q/K/V/O streaming, computed analytically by the
     caller).  EXPERIMENTS.md §Roofline reports both.
+
+    Peaks left None come from ``device_peaks()``.
     """
     from repro.roofline.hlo_cost import analyze_hlo
+
+    peaks = device_peaks()
+    peak_flops = peak_flops or peaks.flops
+    hbm_bw = hbm_bw or peaks.hbm_bw
+    ici_bw = ici_bw or peaks.ici_bw
 
     hlo = compiled.as_text()
     cost = analyze_hlo(

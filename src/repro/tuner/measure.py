@@ -29,7 +29,8 @@ from repro.tuner.model import Candidate
 class Trial:
     candidate: Candidate
     seconds: float  # min over interleaved timed rounds of one multiply
-    error: str = ""  # non-empty when the trial failed (candidate skipped)
+    error: str = ""  # non-empty when the trial failed (candidate skipped;
+    # never on a TPU, where a failing trial raises)
 
     @property
     def ok(self) -> bool:
@@ -50,9 +51,12 @@ def measure_candidates(
 
     Operands may be replicated ``BlockSparseMatrix`` (mesh passed through)
     or ``ShardedBSM`` (already on the mesh — the trial measures exactly
-    the device-resident path the application will run).  A candidate that
-    fails to build/execute is returned with its error instead of aborting
-    the whole tuning pass.
+    the device-resident path the application will run).  Off a TPU, a
+    candidate that fails to build/execute is returned with its error
+    instead of aborting the whole tuning pass.  On a TPU it raises: the
+    model only offers candidates the chip can run, so a failure there
+    (a Mosaic refusal, an allocation failure) is a fault to surface, not
+    a lost race.
     """
     from repro.core.bsm import ShardedBSM
     from repro.core.engine import multiply
@@ -81,6 +85,7 @@ def measure_candidates(
         # under-reports the candidate
         jax.block_until_ready((out.blocks, out.mask, out.norms))
 
+    on_tpu = jax.default_backend() == "tpu"
     runners: dict[int, object] = {}
     best: dict[int, float] = {}
     errors: dict[int, str] = {}
@@ -91,6 +96,8 @@ def measure_candidates(
             runners[i] = run
             best[i] = float("inf")
         except Exception as e:  # noqa: BLE001 - surface per-candidate
+            if on_tpu:
+                raise
             errors[i] = repr(e)
     for _ in range(reps):  # interleaved rounds (see module docstring)
         for i, run in list(runners.items()):
@@ -99,6 +106,8 @@ def measure_candidates(
                 wait(run())
                 best[i] = min(best[i], time.perf_counter() - t0)
             except Exception as e:  # noqa: BLE001 - contain per candidate
+                if on_tpu:
+                    raise
                 errors[i] = repr(e)
                 del runners[i]  # a failed candidate is out of the race
                 del best[i]

@@ -21,34 +21,29 @@ keep the measured stage short, exactly as in DBCSR's autotuning
 (arXiv:1910.13555) and Hong et al.'s sparsity-aware algorithm selection
 (arXiv:2408.14558).
 
-Absolute times use TPU-v5e roofline constants, so on other hardware they
-are wrong in scale but consistent in *ranking* — which is all the prune
-needs; measurement has the final word.
+Absolute times use the device's published peaks
+(``roofline.device_peaks``; off a TPU, the v5e as a modelling target, so
+there they are wrong in scale but consistent in *ranking* — which is all
+the prune needs); measurement has the final word.
 """
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass, field
 
 import numpy as np
 
 from repro.core import commvolume
 from repro.core import plan as plan_mod
-from repro.core.local_mm import backend_local_cost, local_stage_cost
+from repro.core.local_mm import (  # noqa: F401 - re-exported
+    choose_local_backend,
+    compacted_backend,
+    device_memory_budget,
+    local_stage_cost,
+)
 from repro.core.topology import validate_l
-from repro.roofline import ICI_BW, PEAK_FLOPS
+from repro.roofline import device_peaks
 from repro.tuner.features import PairFeatures
-
-# per-device memory budget for candidate pruning: TPU v5e HBM with a 10%
-# reserve, overridable for tests / other targets
-_DEFAULT_BUDGET = 0.9 * 16e9
-
-
-def device_memory_budget() -> float:
-    """Per-device byte budget (``REPRO_DEVICE_MEMORY_BYTES`` overrides)."""
-    raw = os.environ.get("REPRO_DEVICE_MEMORY_BYTES", "").strip()
-    return float(raw) if raw else _DEFAULT_BUDGET
 
 
 # modeled per-tick dispatch/latency overhead: serializes the many-tick
@@ -197,10 +192,8 @@ def enumerate_candidates(
     elif ok is None:
         transports = tuple(t for t in transports if t == "dense")
     if backends is None:
-        import jax
-
-        backends = ("jnp", "pallas") if jax.default_backend() == "tpu" \
-            else ("jnp", "stacks")
+        backends = ("jnp", compacted_backend(
+            feats.bs_r, feats.bs_k, feats.bs_c, np.dtype(feats.dtype)))
     assign_map = assignment_space(counts, mesh, assigns=assigns)
 
     pairs: list[tuple[str, int | None]] = []
@@ -314,7 +307,8 @@ def estimate_candidate(
         nb_k=feats.nb_k, nb_c=feats.nb_c,
         bs_k=feats.bs_k, bs_c=feats.bs_c,
     )
-    comm_s = vol.total / ICI_BW + plan.ticks * TICK_OVERHEAD_S
+    peaks = device_peaks()
+    comm_s = vol.total / peaks.ici_bw + plan.ticks * TICK_OVERHEAD_S
 
     ndev = _n_devices(mesh)
     if cand.backend == "jnp":
@@ -331,7 +325,7 @@ def estimate_candidate(
         dtype=feats.dtype, tile=cand.tile,
         capacity=cand.stack_capacity,
     )
-    compute_s = lc.effective / ndev / PEAK_FLOPS
+    compute_s = lc.effective / ndev / peaks.flops
     if cand.backend != "jnp":
         # mean-load cost -> slowest-device cost (see the docstring)
         imb = imbalance if imbalance is not None else feats.imbalance
@@ -428,27 +422,6 @@ def rank_candidates(
     if top_k is not None:
         feasible = feasible[:top_k]
     return ModelReport(ranked=tuple(feasible), pruned=pruned)
-
-
-def choose_local_backend(
-    ni: int, nk: int, nj: int,
-    bs_r: int, bs_k: int, bs_c: int,
-    fill: float,
-) -> str:
-    """Dense-vs-compacted local backend from the analytic cost model —
-    the generalization of the old fixed occupancy threshold: the
-    crossover now follows ``local_mm.backend_local_cost`` (and therefore
-    moves with rectangular block shapes), instead of a hard-coded fill.
-    Returns "jnp" or the compacted family's platform flavor."""
-    import jax
-
-    dense = backend_local_cost(ni, nk, nj, bs_r, bs_k, bs_c,
-                               fill=1.0, backend="jnp")
-    compact = backend_local_cost(ni, nk, nj, bs_r, bs_k, bs_c,
-                                 fill=fill, backend="stacks")
-    if dense <= compact:
-        return "jnp"
-    return "pallas" if jax.default_backend() == "tpu" else "stacks"
 
 
 def chain_safe(cand: Candidate, *, envelope: bool = False) -> bool:

@@ -22,6 +22,8 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+from repro.launch.mesh import make_mesh  # noqa: E402
+
 
 # ---------------------------------------------------------------------------
 # checks
@@ -97,10 +99,9 @@ def check_stacks_backends():
                 np.asarray(c.to_dense()), ref, rtol=1e-5, atol=1e-5,
                 err_msg=f"{eng}/{be}")
     # non-square pull grid (forced virtual L) + stacked (l, r, c) mesh
-    from jax.sharding import Mesh
 
     devs = np.array(jax.devices()[:8])
-    mesh24 = Mesh(devs.reshape(2, 4), ("r", "c"))
+    mesh24 = make_mesh((2, 4), ("r", "c"), devices=devs)
     for eng in ("onesided", "twofive"):
         c = multiply(a, b, mesh24, engine=eng, threshold=thr, backend="stacks")
         np.testing.assert_allclose(
@@ -129,7 +130,7 @@ def check_engines_rectangular():
     b = B.random_bsm(jax.random.key(3), nb=8, bs=4, occupancy=0.5)
     ref = np.asarray(multiply_reference(a, b).to_dense())
     for shape in ((2, 4), (4, 2), (1, 8)):
-        mesh = jax.make_mesh(shape, ("r", "c"))
+        mesh = make_mesh(shape, ("r", "c"))
         for eng in ("gather", "onesided"):
             c = multiply(a, b, mesh, engine=eng)
             np.testing.assert_allclose(
@@ -187,7 +188,6 @@ def check_tensor():
     uneven-L mesh; a sharded chain stays device-resident between
     contractions; and non-identity block→device assignments on the
     rectangular matricized product are rejected loudly."""
-    from jax.sharding import Mesh
 
     from repro.core import tensor as T
     from repro.core.engine import multiply
@@ -202,7 +202,7 @@ def check_tensor():
     meshes = {
         "2x2": (make_spgemm_mesh(p=2),
                 ("cannon", "onesided", "gather", "twofive")),
-        "2x4": (Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("r", "c")),
+        "2x4": (make_mesh((2, 4), ("r", "c"), devices=jax.devices()[:8]),
                 ("onesided", "gather", "twofive")),
         "stacked": (make_spgemm_mesh(p=2, l=4), ("twofive",)),
     }
@@ -519,7 +519,6 @@ def check_transport():
     plus: the auto mode resolves compressed at low fill and dense at
     high fill, capacities are served from the signature cache on
     repeats, and the REPRO_TRANSPORT env override forces the mode."""
-    from jax.sharding import Mesh
 
     from repro.core import bsm as B
     from repro.core import plan as plan_mod
@@ -528,8 +527,8 @@ def check_transport():
     from repro.launch.mesh import make_spgemm_mesh
 
     mesh2 = make_spgemm_mesh(p=2)
-    mesh24 = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("r", "c"))
-    mesh42 = Mesh(np.array(jax.devices()[:8]).reshape(4, 2), ("r", "c"))
+    mesh24 = make_mesh((2, 4), ("r", "c"), devices=jax.devices()[:8])
+    mesh42 = make_mesh((4, 2), ("r", "c"), devices=jax.devices()[:8])
     mesh_uneven = make_spgemm_mesh(p=2, l=4)  # L does not divide the side
     grids = (
         (mesh2, ("cannon", "onesided", "gather", "twofive")),
@@ -630,11 +629,9 @@ def check_tuner_auto():
     thr = 1e-6
     ref = np.asarray(multiply_reference(a, b, threshold=thr).to_dense())
 
-    from jax.sharding import Mesh
-
     meshes = [
         make_spgemm_mesh(p=2),
-        Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("r", "c")),
+        make_mesh((2, 4), ("r", "c"), devices=jax.devices()[:8]),
         make_spgemm_mesh(p=2, l=2),
     ]
     for mesh in meshes:
@@ -710,7 +707,6 @@ def check_assignment():
     * balancing pays: on the hub-skewed zipf pattern the nnz_greedy
       layout yields a strictly smaller compacted stack capacity.
     """
-    from jax.sharding import Mesh
 
     from repro.core import bsm as B
     from repro.core import distribute as D
@@ -724,7 +720,7 @@ def check_assignment():
                     zipf_alpha=1.4, seed=15)
     a, b = z.build()
     mesh2 = make_spgemm_mesh(p=2)
-    mesh24 = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("r", "c"))
+    mesh24 = make_mesh((2, 4), ("r", "c"), devices=jax.devices()[:8])
     mesh_uneven = make_spgemm_mesh(p=2, l=4)  # L does not divide the side
     # (mesh, engines, backends): compacted backends ride along where the
     # transport/stacks checks already cover that mesh class
@@ -835,7 +831,7 @@ def check_assignment():
                      zipf_alpha=1.4, seed=15)
     za, zb = zz.build()
     ok = np.asarray(za.mask)[:, :, None] & np.asarray(zb.mask)[None, :, :]
-    mesh44 = Mesh(np.array(jax.devices()[:16]).reshape(4, 4), ("r", "c"))
+    mesh44 = make_mesh((4, 4), ("r", "c"), devices=jax.devices()[:16])
     zasg = D.assignment_for(
         "nnz_greedy", D.product_counts(np.asarray(za.mask),
                                        np.asarray(zb.mask)), (4, 4))
@@ -903,7 +899,7 @@ def check_train_steps():
     from repro.parallel.sharding import batch_spec
 
     cfg = get_arch("olmo_1b").reduced()
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     shape = ShapeConfig("t", seq_len=64, global_batch=8, kind="train")
 
     for compress in (False, True):
@@ -943,7 +939,7 @@ def check_serve_steps():
     from repro.models import transformer as T
 
     cfg = get_arch("olmo_1b").reduced()
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     b, s = 4, 32
     shape_d = ShapeConfig("d", seq_len=s, global_batch=b, kind="decode")
     shape_p = ShapeConfig("p", seq_len=s, global_batch=b, kind="prefill")
@@ -988,8 +984,8 @@ def check_checkpoint_cross_mesh():
     from repro.checkpoint import restore_checkpoint, save_checkpoint
     import tempfile
 
-    mesh_a = jax.make_mesh((4, 1), ("data", "model"))
-    mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+    mesh_a = make_mesh((4, 1), ("data", "model"))
+    mesh_b = make_mesh((2, 2), ("data", "model"))
     x = jnp.arange(64.0).reshape(8, 8)
     xa = jax.device_put(x, NamedSharding(mesh_a, P("data", None)))
     tree = {"w": xa, "step": jnp.asarray(3)}
@@ -1010,7 +1006,7 @@ def check_data_global_batch():
     from repro.data.pipeline import DataConfig, SyntheticLMData, make_global_batch
     from repro.parallel.sharding import batch_spec
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     d = SyntheticLMData(DataConfig(vocab=64, seq_len=16, global_batch=8))
     spec = batch_spec(mesh, 8, 16)
     gb = make_global_batch(d, 2, mesh, spec)
@@ -1025,7 +1021,7 @@ def check_matmul_2p5d():
     """The paper's 2.5D schedule on the LM-head matmul: exact vs x @ w."""
     from repro.parallel.matmul_2p5d import matmul_2p5d_shardmap, plan_2p5d
 
-    mesh = jax.make_mesh((2, 2, 4), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 4), ("pod", "data", "model"))
     t, dm, v = 16, 32, 64
     x = jax.random.normal(jax.random.key(0), (t, dm))
     w = jax.random.normal(jax.random.key(1), (dm, v))
@@ -1046,7 +1042,7 @@ def check_compressed_allreduce():
         init_compress_state,
     )
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     fn = compressed_allreduce_shardmap(mesh, axis="data")
     g = jax.random.normal(jax.random.key(0), (4, 64)) * 1e-2
     r0 = jnp.zeros((4, 64), jnp.float32)
@@ -1093,7 +1089,7 @@ def check_microbatch_equivalence():
     from repro.models import transformer as T
 
     cfg = get_arch("olmo_1b").reduced()
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     shape = ShapeConfig("t", 64, 8, "train")
     opt = AdamWConfig(lr=1e-3, weight_decay=0.0)
     batch = {
@@ -1129,7 +1125,7 @@ def check_pipeline():
     """GPipe schedule over a 4-stage axis == sequential composition."""
     from repro.parallel.pipeline import pipeline_shardmap, split_microbatches
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     d = 16
     ws = jax.random.normal(jax.random.key(0), (4, d, d)) * (d**-0.5)
 

@@ -8,6 +8,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import bsm as B
+from repro.launch.mesh import make_mesh
 
 
 def test_to_dense_roundtrip():
@@ -186,7 +187,7 @@ def test_derived_norms_match_make_bsm():
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("r", "c"))
+    return make_mesh((1, 1), ("r", "c"))
 
 
 def test_sharded_bsm_roundtrip_and_algebra():
@@ -229,7 +230,7 @@ def test_sharded_bsm_identity_and_errors():
     np.testing.assert_allclose(np.asarray(i.to_dense()), np.eye(16))
     m = B.random_bsm(jax.random.key(14), nb=5, bs=2, occupancy=0.5)
     with pytest.raises(ValueError):
-        B.shard_bsm(m, jax.make_mesh((1,), ("r",)))  # no 'c' axis
+        B.shard_bsm(m, make_mesh((1,), ("r",)))  # no 'c' axis
 
 
 def test_sharded_multiply_reference_parity():

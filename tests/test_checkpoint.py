@@ -15,6 +15,7 @@ from repro.checkpoint import (
     restore_checkpoint,
     save_checkpoint,
 )
+from repro.launch.mesh import make_mesh
 
 
 def _tree(seed=0):
@@ -94,7 +95,7 @@ def test_manager_auto_resume(tmp_path):
 
 
 def test_manifest_carries_mesh(tmp_path):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     save_checkpoint(str(tmp_path), 1, _tree(), mesh=mesh)
     with open(tmp_path / "step_000000001" / "manifest.json") as f:
         m = json.load(f)

@@ -94,6 +94,27 @@ def test_interpret_env_override(monkeypatch):
     assert _default_interpret() is (jax.default_backend() != "tpu")
 
 
+def test_interpreter_on_tpu_only_when_passed_explicitly(monkeypatch):
+    """On a TPU the platform default is compiled Mosaic, and an
+    environment that forces the interpreter there is refused."""
+    from repro.kernels.ops import _default_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert _default_interpret() is False
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert _default_interpret() is False
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(ValueError, match="interpret=True explicitly"):
+        _default_interpret()
+    a = jnp.ones((1, 1, 8, 8))
+    with pytest.raises(ValueError, match="interpret=True explicitly"):
+        ops.block_spgemm(a, a, jnp.ones((1, 1, 1), bool))
+    # an explicit interpret=True never consults the environment
+    out = ops.block_spgemm(a, a, jnp.ones((1, 1, 1), bool), interpret=True)
+    np.testing.assert_allclose(np.asarray(out), 8.0)
+
+
 def test_block_spgemm_rectangular_blocks():
     """bs_r != bs_k != bs_c through the scalar-prefetch kernel."""
     ni, nk, nj, bs_r, bs_k, bs_c = 2, 3, 4, 8, 16, 4
@@ -164,6 +185,38 @@ def test_block_spgemm_stacks_grid_is_capacity():
     # grid = (n_tm, n_tn, capacity, n_tk): whole-block default tile at
     # bs=8 puts all the tiling dims at 1 — work still scales with capacity
     assert grids == [(1, 1, 8, 1)], grids
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("chunk", [3, 5, 8])
+def test_block_spgemm_chunked_launches_straddle_k_runs(chunk, dtype):
+    """A product list longer than one launch runs as several launches; a
+    k-run cut by a launch boundary (runs of length nk=4 against chunks of
+    3, 5 and 8 over a ragged capacity) accumulates exactly as in one."""
+    from repro.kernels.block_spgemm import block_spgemm_stacks
+    from repro.kernels.stacks import compact_pair_mask
+
+    ni, nk, nj, bs = 3, 4, 2, 8
+    a = jax.random.normal(jax.random.key(70), (ni, nk, bs, bs), dtype)
+    b = jax.random.normal(jax.random.key(71), (nk, nj, bs, bs), dtype)
+    ok = jnp.ones((ni, nk, nj), bool).at[2, 1, 0].set(False)
+    stacks = compact_pair_mask(ok, capacity=27)  # 23 products + 4 padding
+    out = block_spgemm_stacks(a, b, stacks, ni=ni, nj=nj, interpret=True,
+                              chunk=chunk)
+    want = ref.block_spgemm_ref(a, b, ok)
+    one = block_spgemm_stacks(a, b, stacks, ni=ni, nj=nj, interpret=True)
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(one, np.float32), rtol=tol, atol=tol)
+    # and through the public entry point, with the unvisited-tile mask
+    ok2 = ok.at[1].set(False)
+    out2 = block_spgemm(a, b, ok2, capacity=16, chunk=chunk, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out2, np.float32),
+        np.asarray(ref.block_spgemm_ref(a, b, ok2), np.float32),
+        rtol=tol, atol=tol)
 
 
 # ---------------------------------------------------------------------------

@@ -363,3 +363,95 @@ def test_stacks_mm_direct_vs_einsum():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
     )
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,want",
+    [
+        ((128, 128, 128), jnp.float32, "pallas"),
+        ((256, 256, 256), jnp.float32, "pallas"),
+        ((128, 128, 128), jnp.bfloat16, "pallas"),
+        ((23, 23, 23), jnp.float32, "stacks"),
+        ((6, 6, 6), jnp.float32, "stacks"),
+        ((32, 32, 32), jnp.float32, "stacks"),
+        ((8, 128, 64), jnp.float32, "stacks"),
+    ],
+)
+def test_compacted_backend_pallas_only_for_lane_aligned_blocks(
+    monkeypatch, shape, dtype, want
+):
+    """On a TPU the Pallas kernel is chosen only where the block shape has
+    a tile compiled Mosaic accepts; everywhere else, and off the TPU, the
+    XLA stacks path."""
+    from repro.core.local_mm import compacted_backend
+    from repro.tuner.model import choose_local_backend
+
+    assert compacted_backend(*shape, dtype) == "stacks"  # CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert compacted_backend(*shape, dtype) == want
+    # the tuner's dense/compacted choice defers to the same rule
+    assert choose_local_backend(16, 16, 16, *shape, fill=0.01,
+                                dtype=dtype) == want
+
+
+def test_auto_backend_on_tpu_keeps_atomic_blocks_off_pallas(monkeypatch):
+    """engine.choose_backend and the tuner's candidate space, steered to a
+    TPU: bs 23 at low fill goes to stacks, and no pallas candidate of an
+    uncompilable block shape is enumerated."""
+    from repro.launch.mesh import make_mesh
+    from repro.tuner.features import featurize
+    from repro.tuner.model import enumerate_candidates
+
+    a = random_bsm(jax.random.key(12), 16, 23, occupancy=0.05)
+    b = random_bsm(jax.random.key(13), 16, 23, occupancy=0.05)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert choose_backend(a, b) == "stacks"
+    mesh = make_mesh((1, 1), ("r", "c"))
+    f = featurize(a, b, 0.0)
+    ok = np.asarray(a.mask)[:, :, None] & np.asarray(b.mask)[None, :, :]
+    cands = enumerate_candidates(mesh, f, ok=ok, engines=("gather",),
+                                 transports=("dense",))
+    assert {c.backend for c in cands} == {"jnp", "stacks"}
+    pinned = enumerate_candidates(mesh, f, ok=ok, engines=("gather",),
+                                  backends=("pallas",), transports=("dense",))
+    assert pinned == []
+
+
+def test_stacks_memory_priced_with_tpu_tile_padding(monkeypatch):
+    """On a TPU a (capacity, 23, 23) f32 stack is laid out in (8, 128)
+    tiles.  The auto choice and the tuner's Eq. 6 prune count that
+    padding, and a ``stacks`` list that cannot fit the device goes to
+    ``jnp``."""
+    from repro.core import commvolume
+    from repro.core import plan as plan_mod
+    from repro.core.local_mm import choose_local_backend, stack_entry_bytes
+    from repro.kernels.stacks import ProductStacks
+    from repro.launch.mesh import make_mesh
+
+    n_idx = len(ProductStacks._fields)
+    plan = plan_mod.plan_multiply(make_mesh((1, 1), ("r", "c")), "gather")
+    cpu = commvolume.device_memory_bytes(plan, 64, 23, stack_capacity=1024)
+    assert stack_entry_bytes(23, 23, 23) == 4.0 * (3 * 23 * 23 + n_idx)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert stack_entry_bytes(23, 23, 23) == 4.0 * (3 * 24 * 128 + n_idx)
+    assert stack_entry_bytes(128, 128, 128) == 4.0 * (3 * 128 * 128 + n_idx)
+    tpu = commvolume.device_memory_bytes(plan, 64, 23, stack_capacity=1024)
+    assert tpu - cpu == 1024 * 4.0 * 3 * (24 * 128 - 23 * 23)
+    # H2O-DFT-LS shape at nb 512: 2^20 padded entries need ~39 GB
+    dims = (512, 512, 512, 23, 23, 23)
+    assert choose_local_backend(*dims, fill=0.005, capacity=2**20) == "jnp"
+    assert choose_local_backend(*dims, fill=0.005, capacity=2**17) == "stacks"
+    monkeypatch.setenv("REPRO_DEVICE_MEMORY_BYTES", "1e9")
+    assert choose_local_backend(*dims, fill=0.005, capacity=2**17) == "jnp"
+
+
+def test_choose_backend_on_tpu_takes_jnp_where_stacks_cannot_fit(monkeypatch):
+    """engine.choose_backend prices the exact bucketed list: under a
+    budget the padded list exceeds, ``multiply(backend="auto")`` takes the
+    dense einsum."""
+    a = random_bsm(jax.random.key(14), 16, 23, occupancy=0.05)
+    b = random_bsm(jax.random.key(15), 16, 23, occupancy=0.05)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert choose_backend(a, b) == "stacks"
+    monkeypatch.setenv("REPRO_DEVICE_MEMORY_BYTES", "1e5")
+    assert choose_backend(a, b) == "jnp"

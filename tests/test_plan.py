@@ -23,6 +23,7 @@ from repro.core.topology import (
     group_products,
     make_topology,
 )
+from repro.launch.mesh import make_mesh
 
 
 # ---- round partitioning ----------------------------------------------------
@@ -209,11 +210,11 @@ def test_explicit_l_rejected_when_not_honored():
 
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh2d = jax.make_mesh((1, 1), ("r", "c"))
+    mesh2d = make_mesh((1, 1), ("r", "c"))
     for engine in ("cannon", "onesided", "gather"):
         with pytest.raises(ValueError, match="no depth parameter"):
             plan_mod.plan_multiply(mesh2d, engine, 2)
-    mesh3d = jax.make_mesh((1, 1, 1), ("l", "r", "c"))
+    mesh3d = make_mesh((1, 1, 1), ("l", "r", "c"))
     with pytest.raises(ValueError, match="conflicts with the mesh"):
         plan_mod.plan_multiply(mesh3d, "twofive", 4)
 
@@ -241,7 +242,7 @@ def test_program_cache_hits_and_reuse():
 
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a = B.random_bsm(jax.random.key(0), nb=4, bs=4, occupancy=0.6)
     b = B.random_bsm(jax.random.key(1), nb=4, bs=4, occupancy=0.6)
     ref = np.asarray(multiply_reference(a, b).to_dense())
@@ -275,7 +276,7 @@ def test_get_compiled_requires_resolved_transport():
 
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     with pytest.raises(TypeError, match="resolved PanelTransport"):
         plan_mod.get_compiled(mesh, "onesided", 4, 4, "float32",
                               transport="auto")
@@ -291,7 +292,7 @@ def test_build_shard_body_defaults_dense_transport():
 
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     plan = plan_mod.plan_multiply(mesh, "onesided")
     # None -> DENSE inside build_shard_body; an explicit PanelTransport
     # is honored (both bodies construct without error)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.roofline import analyze, model_flops, PEAK_FLOPS
+from repro.roofline import analyze, model_flops, TARGET_PEAKS
 from repro.roofline.hlo_cost import (
     analyze_hlo,
     parse_module,
@@ -141,7 +141,7 @@ def test_analyze_end_to_end_single_device():
     c = f.lower(x, w).compile()
     rep = analyze(c, n_chips=1, model_flops_total=2 * 512**3)
     assert rep.flops_per_device >= 2 * 512**3
-    assert rep.compute_s == pytest.approx(rep.flops_per_device / PEAK_FLOPS)
+    assert rep.compute_s == pytest.approx(rep.flops_per_device / TARGET_PEAKS.flops)
     assert rep.dominant in ("compute", "memory", "collective")
     assert 0.0 < rep.useful_flops_ratio <= 1.2
 
@@ -295,3 +295,22 @@ def test_local_stage_cost_dtype_and_tile_aware():
     assert (
         2 * 3 * 1024 * 1024 * 4 + 1024 * 1024 * 4 > VMEM_BUDGET_BYTES
     )  # the shape above really is over budget, not a model quirk
+
+
+def test_device_peaks_keyed_by_device_kind():
+    """v5e resolves to its published peaks, any non-TPU device to the v5e
+    modelling target, and an unknown TPU kind raises."""
+    from types import SimpleNamespace
+
+    from repro.roofline import DEVICE_PEAKS, TARGET_PEAKS, device_peaks
+
+    v5e = device_peaks(SimpleNamespace(platform="tpu",
+                                       device_kind="TPU v5 lite"))
+    assert v5e is DEVICE_PEAKS["TPU v5 lite"]
+    assert (v5e.flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9, 16e9)
+    assert "Google Cloud" in v5e.source
+    assert device_peaks(SimpleNamespace(platform="cpu",
+                                        device_kind="cpu")) is TARGET_PEAKS
+    assert device_peaks() is TARGET_PEAKS  # this process runs on the CPU
+    with pytest.raises(KeyError, match="TPU v99"):
+        device_peaks(SimpleNamespace(platform="tpu", device_kind="TPU v99"))

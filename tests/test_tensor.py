@@ -23,6 +23,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import tensor as T
 from repro.core.bsm import block_norms
+from repro.launch.mesh import make_mesh
 
 
 def _bit_equal(t1: T.BlockSparseTensor, t2: T.BlockSparseTensor) -> None:
@@ -289,9 +290,7 @@ def test_rectangular_product_rejects_assignment():
     from repro.core import plan as plan_mod
     from repro.core.distribute import Assignment
 
-    mesh = jax.sharding.Mesh(
-        np.array(jax.devices()[:1]).reshape(1, 1), ("r", "c")
-    )
+    mesh = make_mesh((1, 1), ("r", "c"), devices=jax.devices()[:1])
     asg = Assignment("nnz_greedy", perm=(1, 0))
     with pytest.raises(ValueError, match="symmetric"):
         plan_mod.get_compiled(

@@ -23,6 +23,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import plan as plan_mod
 from repro.core import transport as T
+from repro.launch.mesh import make_mesh
 
 
 def _random_panel(seed: int, nr: int, nc: int, occ: float, bs: int = 4):
@@ -124,7 +125,7 @@ def test_plan_panel_parts_pull_vs_shard():
     whole home shards."""
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     for engine in ("gather", "cannon"):
         plan = plan_mod.plan_multiply(mesh, engine)
         assert T.plan_panel_parts(plan) == ((1, 1), (1, 1))
@@ -176,7 +177,7 @@ def test_transport_mode_env_override(monkeypatch):
 def test_get_transport_caps_and_counters():
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     plan_mod.clear_cache()
     mask = np.zeros((8, 8), bool)
     mask[0, :3] = True  # 3 occupied blocks in the single shard
@@ -214,7 +215,7 @@ def test_transport_keys_program_cache():
     from repro.core import bsm as B
     from repro.core.engine import multiply, multiply_reference
 
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a = B.random_bsm(jax.random.key(0), nb=4, bs=4, occupancy=0.3)
     b = B.random_bsm(jax.random.key(1), nb=4, bs=4, occupancy=0.3)
     ref = np.asarray(multiply_reference(a, b).to_dense())
@@ -246,7 +247,7 @@ def test_under_capacity_transport_rejected():
     from repro.core import bsm as B
     from repro.core.engine import multiply
 
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a = B.random_bsm(jax.random.key(0), nb=8, bs=4, occupancy=1.0)
     with pytest.raises(ValueError, match="under-cover"):
         multiply(a, a, mesh, engine="cannon",
@@ -265,7 +266,7 @@ def test_forced_compressed_on_traced_operands_raises():
     from repro.core import bsm as B
     from repro.core.engine import multiply
 
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a = B.random_bsm(jax.random.key(0), nb=4, bs=4, occupancy=0.5)
 
     @jax.jit
@@ -345,7 +346,7 @@ def test_plan_volume_models_wire_width_exactly():
 
     from repro.core import commvolume as CV
 
-    mesh = AbstractMesh((("r", 2), ("c", 2)))
+    mesh = AbstractMesh((2, 2), ("r", "c"))
     for engine in ("cannon", "gather", "onesided"):
         plan = plan_mod.plan_multiply(mesh, engine)
         v32 = CV.plan_volume(plan, 4, 8, itemsize=4.0)
@@ -361,7 +362,7 @@ def test_plan_volume_models_wire_width_exactly():
         n_blocks = v32.ab_volume / (blk32 + 1.0)
         assert vw.ab_volume == pytest.approx(n_blocks * (blk16 + 1.0))
     # the stacked twofive plan: same halving on its gather legs
-    mesh3 = AbstractMesh((("l", 2), ("r", 2), ("c", 2)))
+    mesh3 = AbstractMesh((2, 2, 2), ("l", "r", "c"))
     plan = plan_mod.plan_multiply(mesh3, "twofive")
     v32 = CV.plan_volume(plan, 4, 8, itemsize=4.0)
     vw = CV.plan_volume(

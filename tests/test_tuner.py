@@ -31,6 +31,7 @@ from repro.tuner import (
 )
 from repro.tuner.corpus import corpus, make_mask
 from repro.tuner.db import make_key
+from repro.launch.mesh import make_mesh
 from repro.tuner.model import (
     enumerate_candidates,
     estimate_candidate,
@@ -246,7 +247,7 @@ def test_db_record_persists_transport(tmp_path):
 
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a, b = _pair(nb=4, occupancy=0.4)
     plan_mod.clear_cache()
     db = TuningDB(str(tmp_path / "db.json"))
@@ -272,7 +273,7 @@ def test_pre_transport_db_records_still_warm_hit(tmp_path):
     unchanged, and the record reads as dense transport."""
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a, b = _pair(nb=4, occupancy=0.4)
     f = featurize(a, b, 0.0)
     db = TuningDB(str(tmp_path / "db.json"))
@@ -447,7 +448,7 @@ def test_db_rejects_unknown_schema(tmp_path):
 
 
 def test_auto_multiply_matches_reference():
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a, b = _pair(nb=8, bs=8, occupancy=0.25)
     plan_mod.clear_cache()
     c = multiply(a, b, mesh, engine="auto", threshold=1e-6)
@@ -465,7 +466,7 @@ def test_auto_multiply_matches_reference():
 
 
 def test_auto_warm_db_runs_zero_trials(tmp_path):
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a, b = _pair(nb=8, bs=8, occupancy=0.25, seed=3)
     path = str(tmp_path / "db.json")
     plan_mod.clear_cache()
@@ -487,7 +488,7 @@ def test_auto_warm_db_runs_zero_trials(tmp_path):
 
 
 def test_clear_cache_drops_all_caches(tmp_path):
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a, b = _pair(nb=8, bs=8, occupancy=0.2, seed=5)
     plan_mod.clear_cache()
     tuner.set_default_db(str(tmp_path / "db.json"))
@@ -524,7 +525,7 @@ def test_clear_cache_drops_envelope_and_drift_levels(tmp_path):
     of test_clear_cache_drops_all_caches for the levels PR 8 added)."""
     from repro.core import envelope as E
 
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a, b = _pair(nb=8, bs=8, occupancy=0.3, seed=5)
     plan_mod.clear_cache()
     tuner.set_default_db(str(tmp_path / "db.json"))
@@ -615,7 +616,7 @@ def test_db_record_persists_tile(tmp_path):
 
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a, b = _pair(nb=4, occupancy=0.4)
     plan_mod.clear_cache()
     db = TuningDB(str(tmp_path / "db.json"))
@@ -647,7 +648,7 @@ def test_pre_tile_db_records_still_warm_hit(tmp_path):
     field) still resolves measurement-free."""
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a, b = _pair(nb=4, occupancy=0.4)
     f = featurize(a, b, 0.0)
     db = TuningDB(str(tmp_path / "db.json"))
@@ -749,7 +750,7 @@ def test_db_record_persists_assign(tmp_path):
     use) and survives a JSON round-trip."""
     if len(jax.devices()) != 1:
         pytest.skip("single-device check")
-    mesh = jax.make_mesh((1, 1), ("r", "c"))
+    mesh = make_mesh((1, 1), ("r", "c"))
     a, b = _pair(nb=4, occupancy=0.4)
     plan_mod.clear_cache()
     db = TuningDB(str(tmp_path / "db.json"))
@@ -843,3 +844,18 @@ def test_model_scales_compute_by_imbalance():
     assert dj.compute_s == estimate_candidate(
         Candidate("gather"), mesh, f,
         imbalance=imbs["nnz_greedy"]).compute_s
+
+
+def test_failing_trial_raises_on_tpu_only(monkeypatch):
+    """Off the TPU a candidate that fails is a lost race, recorded in its
+    Trial; on a TPU the same failure raises."""
+    from repro.tuner.measure import measure_candidates
+
+    a, b = _pair(nb=4, bs=4, occupancy=0.5)
+    mesh = make_mesh((1, 1), ("r", "c"))
+    bad = Candidate("no-such-engine")
+    trials = measure_candidates(a, b, mesh, [bad], reps=1)
+    assert not trials[0].ok and "no-such-engine" in trials[0].error
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="no-such-engine"):
+        measure_candidates(a, b, mesh, [bad], reps=1)
