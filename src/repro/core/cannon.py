@@ -51,7 +51,10 @@ def ring_body(
     interpret: bool | None = None,
     transport: T.PanelTransport = T.DENSE,
 ):
-    """The per-shard PTP Cannon body (shards in, C shard out).
+    """The per-shard PTP Cannon body: shards in, ``(cb, cm, calls)``
+    out, the C shard and the operand masks of this shard's local-stage
+    calls (for ``local_mm.product_counts``; the ticks of the scanned ring
+    stacked on a leading axis).
 
     Exposed separately from the executor so iteration chains
     (``core/signiter.py``) can inline the whole multiply into ONE
@@ -78,7 +81,7 @@ def ring_body(
                 xb, xm, T.panel_norms(xb, threshold),
                 yb, ym, T.panel_norms(yb, threshold), **mm_kw,
             )
-            return cb + dcb, cm | dcm
+            return cb + dcb, cm | dcm, (xm, ym)
 
         # --- pre-shift (Algorithm 1): A_ij <- A_{i,(j+i)}, B_ij <- B_{(i+j),j}
         pa = T.permute(T.ingest(tr, tr.cap_a, ab, am), axes, plan.pre_a)
@@ -92,7 +95,8 @@ def ring_body(
         cm = lax.pcast(cm, axes, to="varying")
 
         if ticks == 1:
-            return compute(pa, pb, cb, cm)
+            cb, cm, call = compute(pa, pb, cb, cm)
+            return cb, cm, [call]
 
         # --- double-buffered ring: the hop for tick t+1 is in flight
         # before the GEMM of tick t runs (paper §4 comm/compute overlap)
@@ -103,16 +107,21 @@ def ring_body(
             pa, pb, na, nb_, cb, cm = carry
             fa = T.permute(na, "c", plan.shift_a)
             fb = T.permute(nb_, "r", plan.shift_b)
-            cb, cm = compute(pa, pb, cb, cm)
-            return (na, nb_, fa, fb, cb, cm), None
+            cb, cm, call = compute(pa, pb, cb, cm)
+            return (na, nb_, fa, fb, cb, cm), call
 
+        calls = []
         if ticks > 2:
-            (pa, pb, na, nb_, cb, cm), _ = lax.scan(
+            (pa, pb, na, nb_, cb, cm), scanned = lax.scan(
                 tick, (pa, pb, na, nb_, cb, cm), None, length=ticks - 2
             )
+            calls.append(scanned)
         # last two ticks: compute only, no trailing shift (itick==nticks)
-        cb, cm = compute(pa, pb, cb, cm)
-        return compute(na, nb_, cb, cm)
+        cb, cm, call = compute(pa, pb, cb, cm)
+        calls.append(call)
+        cb, cm, call = compute(na, nb_, cb, cm)
+        calls.append(call)
+        return cb, cm, calls
 
     return body
 
@@ -121,8 +130,9 @@ def ring_executor(plan, **kw):
     """The PTP Cannon engine: plan's pre-shift + V ring hops."""
     blk = P("r", "c", None, None)
     m2 = P("r", "c")
+    body = ring_body(plan, **kw)
     return shard_map(
-        ring_body(plan, **kw),
+        lambda *shards: body(*shards)[:2],
         mesh=plan.mesh,
         # check_vma=False: the pallas backend's pallas_call builds plain
         # ShapeDtypeStructs (no vma annotation); engine outputs are

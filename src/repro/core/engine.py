@@ -54,6 +54,7 @@ from repro.core.local_mm import (
     local_filtered_mm,
 )
 from repro.kernels.stacks import bucket_capacity
+from repro.obs import span
 
 ENGINES = ("cannon", "onesided", "gather", "twofive")
 
@@ -72,7 +73,8 @@ def _host_pair_filter(a: BlockSparseMatrix, b: BlockSparseMatrix,
     """Concrete (i, k, j) filter cube on the host (numpy)."""
     from repro.kernels.stacks import pair_cube
 
-    return pair_cube(a.mask, b.mask, a.norms, b.norms, threshold)
+    with span("spgemm.pair_walk"):
+        return pair_cube(a.mask, b.mask, a.norms, b.norms, threshold)
 
 
 def choose_backend(a: BlockSparseMatrix, b: BlockSparseMatrix,
@@ -283,196 +285,201 @@ def multiply(
     no gather, no re-shard; post-filtering happens shard-local with
     derived norms.  Both operands must be sharded on the same mesh.
     """
-    if engine != "auto" and engine not in ENGINES:
-        raise ValueError(
-            f"unknown engine {engine!r}; one of {ENGINES} or 'auto'"
-        )
-    env = envelope
-    if (
-        env is not None
-        and _is_concrete(a.mask, b.mask)
-        and not env.covers(np.asarray(a.mask, bool),
-                           np.asarray(b.mask, bool))
-    ):
-        # the pattern drifted out of its envelope: abandon the warm path
-        # and re-derive everything exactly for this call
-        plan_mod.note_drift_retune()
-        env = None
-    # None = the caller left the backend open: static engines get the
-    # historical "jnp" default, the tuner gets the full backend space
-    pinned = backend if backend not in (None, "auto") else None
-    if backend is None:
-        backend = "jnp"
-    if isinstance(a, ShardedBSM) or isinstance(b, ShardedBSM):
-        if not (isinstance(a, ShardedBSM) and isinstance(b, ShardedBSM)):
-            raise TypeError(
-                "mixed ShardedBSM / BlockSparseMatrix operands; shard both "
-                "(bsm.shard_bsm) or neither"
+    with span("spgemm.multiply"):
+        if engine != "auto" and engine not in ENGINES:
+            raise ValueError(
+                f"unknown engine {engine!r}; one of {ENGINES} or 'auto'"
             )
-        if a.mesh is not b.mesh and a.mesh != b.mesh:
-            raise ValueError("operands sharded on different meshes")
-        if mesh is not None and mesh is not a.mesh and mesh != a.mesh:
-            raise ValueError("mesh argument conflicts with operand mesh")
-        if c_layout != "2d":
-            raise ValueError("sharded chains require c_layout='2d'")
-        if engine == "auto":
-            # full tuner resolution: one host walk of the device-resident
-            # pattern, amortized by the decision cache across repeats.
-            # assign is pinned to identity — the layout decision was made
-            # at shard_bsm time and the tuner sees the permuted pattern.
-            from repro import tuner
-
-            dec = tuner.autotune(
-                a, b, a.mesh, threshold=threshold, backend=pinned,
-                l=l, interpret=interpret,
-                transport=_transport_pin(transport),
-                assign="identity", envelope=env,
-            )
-            engine, l, backend = dec.engine, dec.l, dec.backend
-            if stack_capacity is None:
-                stack_capacity = dec.stack_capacity
-            if tile is None:
-                tile = dec.tile
-            if transport is None or transport == "auto":
-                # adopt the tuner's measured mode (as resolve_multiply
-                # does) — "auto" left in place would re-resolve through
-                # the static crossover and could contradict the trials
-                transport = dec.transport
-        elif backend == "auto":
-            if env is not None:
-                # envelope fill decides without touching device masks
-                backend = choose_backend(a, b, threshold,
-                                         ok=np.asarray(env.cube))
-            else:
-                # the auto heuristic walks the concrete pattern on the
-                # host — a round-trip the device-resident path avoids
-                backend = "jnp"
-        if backend in ("stacks", "pallas") and stack_capacity is None:
-            if env is not None:
-                # envelope capacity: stable across the whole drifting
-                # stream (one program), no per-call mask sync
-                stack_capacity = plan_mod.get_device_capacity(
-                    env.cube, a.mesh, engine)
-            elif _is_concrete(a.mask, a.norms, b.mask, b.norms):
-                # sound per-device bound from the concrete (and, under a
-                # non-identity assignment, already-permuted) shard masks
-                # — without it the compacted program pads every device to
-                # the full cube and the balanced layout's smaller hot
-                # device buys nothing.  Costs the same per-call host mask
-                # sync the auto transport resolution below already pays;
-                # pass an explicit stack_capacity to skip it.
-                stack_capacity = plan_mod.get_device_capacity(
-                    _host_pair_filter(a, b, threshold), a.mesh, engine)
-        if env is not None:
-            transport = _envelope_transport(
-                env.mask_a, env.mask_b, transport, a.mesh, engine, l)
-        c = plan_mod.execute_sharded(
-            a, b, engine,
-            threshold=threshold, backend=backend, l=l,
-            stack_capacity=stack_capacity, tile=tile, interpret=interpret,
-            transport=transport, assignment=assignment,
-        )
-        eps = threshold if filter_eps is None else filter_eps
-        return c.filter(eps) if eps > 0.0 else c
-    if mesh is None and assignment not in (None, "identity"):
-        raise ValueError(
-            "assignment needs a mesh: a block→device distribution has no "
-            "meaning on a single device"
-        )
-    if engine == "auto":
-        if mesh is None:
-            engine = "twofive"  # single-device: the engine is vestigial
-        else:
-            # delegate the whole (engine, L, backend, capacity, transport,
-            # assignment) decision to the tuner (repro.tuner, DESIGN.md §6)
-            from repro import tuner
-
-            dec = tuner.autotune(
-                a, b, mesh, threshold=threshold, backend=pinned,
-                l=l, interpret=interpret,
-                transport=_transport_pin(transport),
-                assign=_assign_pin(assignment), envelope=env,
-            )
-            engine, l, backend = dec.engine, dec.l, dec.backend
-            if stack_capacity is None:
-                stack_capacity = dec.stack_capacity
-            if tile is None:
-                tile = dec.tile
-            if transport is None or transport == "auto":
-                # adopt the tuner's measured mode (see the sharded path)
-                transport = dec.transport
-            if assignment is None:
-                # adopt the tuner's winning layout (identity when the
-                # pattern is already balanced)
-                assignment = dec.assign
-    # the layout every capacity bound below must be derived from
-    asg = None
-    if mesh is not None:
-        asg = plan_mod.resolve_assignment(assignment, a, b, mesh)
-    # one host walk of the concrete filter cube serves both the auto
-    # heuristic and the distributed capacity bound; an envelope replaces
-    # the walk entirely (its union cube is the bound for the stream)
-    ok_np = None
-    if (
-        env is None
-        and (backend == "auto" or (backend in ("stacks", "pallas")
-                                   and mesh is not None
-                                   and stack_capacity is None))
-        and _is_concrete(a.mask, a.norms, b.mask, b.norms)
-    ):
-        ok_np = _host_pair_filter(a, b, threshold)
-    if backend == "auto":
-        backend = choose_backend(
-            a, b, threshold,
-            ok=np.asarray(env.cube) if env is not None else ok_np,
-        )
-    if mesh is None:
+        env = envelope
         if (
             env is not None
-            and backend in ("stacks", "pallas")
-            and stack_capacity is None
+            and _is_concrete(a.mask, b.mask)
+            and not env.covers(np.asarray(a.mask, bool),
+                               np.asarray(b.mask, bool))
         ):
-            # static envelope capacity routes the whole stream through
-            # one traced compacted program (mask-as-data, no host walks)
-            stack_capacity = env.local_capacity()
-        c = multiply_reference(
-            a, b, threshold=threshold, backend=backend,
-            stack_capacity=stack_capacity, tile=tile, interpret=interpret,
-            ok=ok_np,
-        )
-    else:
-        if backend in ("stacks", "pallas") and stack_capacity is None:
-            # capacity must cover the PERMUTED pattern's hottest device —
-            # the layout the engine actually partitions
-            ok_cap = None
-            if env is not None:
-                ok_cap = np.asarray(env.cube)
-            elif ok_np is not None:
-                ok_cap = ok_np
-            if ok_cap is not None:
-                if asg is not None:
-                    from repro.core.distribute import permute_cube
+            # the pattern drifted out of its envelope: abandon the warm path
+            # and re-derive everything exactly for this call
+            plan_mod.note_drift_retune()
+            env = None
+        # None = the caller left the backend open: static engines get the
+        # historical "jnp" default, the tuner gets the full backend space
+        pinned = backend if backend not in (None, "auto") else None
+        if backend is None:
+            backend = "jnp"
+        if isinstance(a, ShardedBSM) or isinstance(b, ShardedBSM):
+            if not (isinstance(a, ShardedBSM) and isinstance(b, ShardedBSM)):
+                raise TypeError(
+                    "mixed ShardedBSM / BlockSparseMatrix operands; shard both "
+                    "(bsm.shard_bsm) or neither"
+                )
+            if a.mesh is not b.mesh and a.mesh != b.mesh:
+                raise ValueError("operands sharded on different meshes")
+            if mesh is not None and mesh is not a.mesh and mesh != a.mesh:
+                raise ValueError("mesh argument conflicts with operand mesh")
+            if c_layout != "2d":
+                raise ValueError("sharded chains require c_layout='2d'")
+            if engine == "auto":
+                # full tuner resolution: one host walk of the device-resident
+                # pattern, amortized by the decision cache across repeats.
+                # assign is pinned to identity — the layout decision was made
+                # at shard_bsm time and the tuner sees the permuted pattern.
+                from repro import tuner
 
-                    ok_cap = permute_cube(ok_cap, asg.perm)
-                stack_capacity = plan_mod.get_device_capacity(
-                    ok_cap, mesh, engine)
-        if env is not None:
-            em_a, em_b = env.mask_a, env.mask_b
-            if asg is not None:
-                p = np.asarray(asg.perm)
-                em_a, em_b = em_a[p][:, p], em_b[p][:, p]
-            transport = _envelope_transport(
-                em_a, em_b, transport, mesh, engine, l)
-        c = plan_mod.execute(
-            a, b, mesh, engine,
-            threshold=threshold, backend=backend, c_layout=c_layout, l=l,
-            stack_capacity=stack_capacity, tile=tile, interpret=interpret,
-            transport=transport, assignment=asg,
-        )
-    eps = threshold if filter_eps is None else filter_eps
-    if eps > 0.0:
-        c = filter_bsm(c, eps)
-    return c
+                dec = tuner.autotune(
+                    a, b, a.mesh, threshold=threshold, backend=pinned,
+                    l=l, interpret=interpret,
+                    transport=_transport_pin(transport),
+                    assign="identity", envelope=env,
+                )
+                engine, l, backend = dec.engine, dec.l, dec.backend
+                if stack_capacity is None:
+                    stack_capacity = dec.stack_capacity
+                if tile is None:
+                    tile = dec.tile
+                if transport is None or transport == "auto":
+                    # adopt the tuner's measured mode (as resolve_multiply
+                    # does) — "auto" left in place would re-resolve through
+                    # the static crossover and could contradict the trials
+                    transport = dec.transport
+            elif backend == "auto":
+                if env is not None:
+                    # envelope fill decides without touching device masks
+                    backend = choose_backend(a, b, threshold,
+                                             ok=np.asarray(env.cube))
+                else:
+                    # the auto heuristic walks the concrete pattern on the
+                    # host — a round-trip the device-resident path avoids
+                    backend = "jnp"
+            if backend in ("stacks", "pallas") and stack_capacity is None:
+                if env is not None:
+                    # envelope capacity: stable across the whole drifting
+                    # stream (one program), no per-call mask sync
+                    stack_capacity = plan_mod.get_device_capacity(
+                        env.cube, a.mesh, engine)
+                elif _is_concrete(a.mask, a.norms, b.mask, b.norms):
+                    # sound per-device bound from the concrete (and, under a
+                    # non-identity assignment, already-permuted) shard masks
+                    # — without it the compacted program pads every device to
+                    # the full cube and the balanced layout's smaller hot
+                    # device buys nothing.  Costs the same per-call host mask
+                    # sync the auto transport resolution below already pays;
+                    # pass an explicit stack_capacity to skip it.
+                    stack_capacity = plan_mod.get_device_capacity(
+                        _host_pair_filter(a, b, threshold), a.mesh, engine)
+            if env is not None:
+                transport = _envelope_transport(
+                    env.mask_a, env.mask_b, transport, a.mesh, engine, l)
+            with span("spgemm.dispatch"):
+                c = plan_mod.execute_sharded(
+                    a, b, engine,
+                    threshold=threshold, backend=backend, l=l,
+                    stack_capacity=stack_capacity, tile=tile,
+                    interpret=interpret, transport=transport,
+                    assignment=assignment,
+                )
+            eps = threshold if filter_eps is None else filter_eps
+            return c.filter(eps) if eps > 0.0 else c
+        if mesh is None and assignment not in (None, "identity"):
+            raise ValueError(
+                "assignment needs a mesh: a block→device distribution has no "
+                "meaning on a single device"
+            )
+        if engine == "auto":
+            if mesh is None:
+                engine = "twofive"  # single-device: the engine is vestigial
+            else:
+                # delegate the whole (engine, L, backend, capacity, transport,
+                # assignment) decision to the tuner (repro.tuner, DESIGN.md §6)
+                from repro import tuner
+
+                dec = tuner.autotune(
+                    a, b, mesh, threshold=threshold, backend=pinned,
+                    l=l, interpret=interpret,
+                    transport=_transport_pin(transport),
+                    assign=_assign_pin(assignment), envelope=env,
+                )
+                engine, l, backend = dec.engine, dec.l, dec.backend
+                if stack_capacity is None:
+                    stack_capacity = dec.stack_capacity
+                if tile is None:
+                    tile = dec.tile
+                if transport is None or transport == "auto":
+                    # adopt the tuner's measured mode (see the sharded path)
+                    transport = dec.transport
+                if assignment is None:
+                    # adopt the tuner's winning layout (identity when the
+                    # pattern is already balanced)
+                    assignment = dec.assign
+        # the layout every capacity bound below must be derived from
+        asg = None
+        if mesh is not None:
+            asg = plan_mod.resolve_assignment(assignment, a, b, mesh)
+        # one host walk of the concrete filter cube serves both the auto
+        # heuristic and the distributed capacity bound; an envelope replaces
+        # the walk entirely (its union cube is the bound for the stream)
+        ok_np = None
+        if (
+            env is None
+            and (backend == "auto" or (backend in ("stacks", "pallas")
+                                       and mesh is not None
+                                       and stack_capacity is None))
+            and _is_concrete(a.mask, a.norms, b.mask, b.norms)
+        ):
+            ok_np = _host_pair_filter(a, b, threshold)
+        if backend == "auto":
+            backend = choose_backend(
+                a, b, threshold,
+                ok=np.asarray(env.cube) if env is not None else ok_np,
+            )
+        if mesh is None:
+            if (
+                env is not None
+                and backend in ("stacks", "pallas")
+                and stack_capacity is None
+            ):
+                # static envelope capacity routes the whole stream through
+                # one traced compacted program (mask-as-data, no host walks)
+                stack_capacity = env.local_capacity()
+            with span("spgemm.dispatch"):
+                c = multiply_reference(
+                    a, b, threshold=threshold, backend=backend,
+                    stack_capacity=stack_capacity, tile=tile,
+                    interpret=interpret, ok=ok_np,
+                )
+        else:
+            if backend in ("stacks", "pallas") and stack_capacity is None:
+                # capacity must cover the PERMUTED pattern's hottest device —
+                # the layout the engine actually partitions
+                ok_cap = None
+                if env is not None:
+                    ok_cap = np.asarray(env.cube)
+                elif ok_np is not None:
+                    ok_cap = ok_np
+                if ok_cap is not None:
+                    if asg is not None:
+                        from repro.core.distribute import permute_cube
+
+                        ok_cap = permute_cube(ok_cap, asg.perm)
+                    stack_capacity = plan_mod.get_device_capacity(
+                        ok_cap, mesh, engine)
+            if env is not None:
+                em_a, em_b = env.mask_a, env.mask_b
+                if asg is not None:
+                    p = np.asarray(asg.perm)
+                    em_a, em_b = em_a[p][:, p], em_b[p][:, p]
+                transport = _envelope_transport(
+                    em_a, em_b, transport, mesh, engine, l)
+            with span("spgemm.dispatch"):
+                c = plan_mod.execute(
+                    a, b, mesh, engine,
+                    threshold=threshold, backend=backend, c_layout=c_layout,
+                    l=l, stack_capacity=stack_capacity, tile=tile,
+                    interpret=interpret, transport=transport, assignment=asg,
+                )
+        eps = threshold if filter_eps is None else filter_eps
+        if eps > 0.0:
+            c = filter_bsm(c, eps)
+        return c
 
 
 def _envelope_transport(mask_a, mask_b, transport, mesh, engine: str,

@@ -41,7 +41,9 @@ def gather_body(
 ):
     """The per-shard all-gather body (exposed for chain fusion — the
     panel all-gathers here are the engine's *internal* pulls, not a
-    C gather; C comes home sharded).
+    C gather; C comes home sharded).  Returns ``(cb, cm, calls)``, the
+    C shard and the operand masks of its one local-stage call (for
+    ``local_mm.product_counts``).
 
     The gathers go through the transport layer: dense moves blocks +
     mask (norms recomputed after the gather), compressed all-gathers
@@ -55,12 +57,13 @@ def gather_body(
         # pull the full block row of A / block column of B from home
         ab, am = T.all_gather_panels(tr, tr.cap_a, ab, am, "c", axis=1)
         bb, bm = T.all_gather_panels(tr, tr.cap_b, bb, bm, "r", axis=0)
-        return local_filtered_mm(
+        cb, cm = local_filtered_mm(
             ab, am, T.panel_norms(ab, threshold),
             bb, bm, T.panel_norms(bb, threshold),
             threshold=threshold, backend=backend,
             stack_capacity=stack_capacity, tile=tile, interpret=interpret,
         )
+        return cb, cm, [(am, bm)]
 
     return body
 
@@ -68,8 +71,9 @@ def gather_body(
 def gather_executor(plan, **kw):
     blk = P("r", "c", None, None)
     m2 = P("r", "c")
+    body = gather_body(plan, **kw)
     return shard_map(
-        gather_body(plan, **kw),
+        lambda *shards: body(*shards)[:2],
         mesh=plan.mesh,
         # check_vma=False: the pallas backend's pallas_call builds plain
         # ShapeDtypeStructs (no vma annotation); engine outputs are

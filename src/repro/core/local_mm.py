@@ -344,32 +344,80 @@ def local_filtered_mm(
     (compiled Mosaic on TPU, interpreter elsewhere — see
     ``repro.config.pallas_interpret``).
     """
-    ni, nk = a_blocks.shape[:2]
-    nj = b_blocks.shape[1]
-    ok = pair_filter(a_mask, a_norms, b_mask, b_norms, threshold)
-    if backend == "pallas":
-        from repro.kernels import ops as kops
+    with jax.named_scope("spgemm.local"):
+        ni, nk = a_blocks.shape[:2]
+        nj = b_blocks.shape[1]
+        ok = pair_filter(a_mask, a_norms, b_mask, b_norms, threshold)
+        if backend == "pallas":
+            from repro.kernels import ops as kops
 
-        c_blocks = kops.block_spgemm(
-            a_blocks, b_blocks, ok, capacity=stack_capacity, tile=tile,
-            interpret=interpret,
-        )
-    elif backend == "stacks":
-        cap = resolve_capacity(stack_capacity, ni * nk * nj)
-        stacks = compact_pair_mask(ok, capacity=cap)
-        c_blocks = stacks_mm(
-            a_blocks, b_blocks, stacks, ni=ni, nj=nj, precision=precision
-        )
-    elif backend == "jnp":
-        okf = ok.astype(jnp.float32)
-        c_blocks = jnp.einsum(
-            "ikj,ikab,kjbc->ijac",
-            okf,
-            a_blocks.astype(jnp.float32),
-            b_blocks.astype(jnp.float32),
-            precision=precision,
-        ).astype(a_blocks.dtype)
-    else:
-        raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
-    c_mask = jnp.any(ok, axis=1)
-    return c_blocks, c_mask
+            c_blocks = kops.block_spgemm(
+                a_blocks, b_blocks, ok, capacity=stack_capacity, tile=tile,
+                interpret=interpret,
+            )
+        elif backend == "stacks":
+            cap = resolve_capacity(stack_capacity, ni * nk * nj)
+            stacks = compact_pair_mask(ok, capacity=cap)
+            c_blocks = stacks_mm(
+                a_blocks, b_blocks, stacks, ni=ni, nj=nj, precision=precision
+            )
+        elif backend == "jnp":
+            okf = ok.astype(jnp.float32)
+            c_blocks = jnp.einsum(
+                "ikj,ikab,kjbc->ijac",
+                okf,
+                a_blocks.astype(jnp.float32),
+                b_blocks.astype(jnp.float32),
+                precision=precision,
+            ).astype(a_blocks.dtype)
+        else:
+            raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
+        c_mask = jnp.any(ok, axis=1)
+        return c_blocks, c_mask
+
+
+# Block-product counts leave the device as int32 (hi, lo) pairs, value
+# hi * 2**16 + lo: exact for any count below 2**47, where one int32 would
+# overflow at a 1,290-block cube.
+_COUNT_BITS = 16
+_LO = (1 << _COUNT_BITS) - 1
+
+
+def count_value(pair) -> int:
+    """The Python int of one (hi, lo) count pair fetched to the host."""
+    hi, lo = (int(v) for v in pair)
+    return (hi << _COUNT_BITS) + lo
+
+
+def product_counts(calls, *, backend: str = "jnp",
+                   stack_capacity: int | None = None) -> jax.Array:
+    """Block products of local-stage calls, given by each call's operand
+    masks ``(a_mask, b_mask)``, (ni, nk) and (nk, nj), or stacked calls
+    with leading axes.  Returns a (2, 2) int32 array of (hi, lo) pairs.
+    Row 0: the products whose A and B blocks are both present,
+    sum_k colcount_A(k) * rowcount_B(k), the same whichever backend
+    runs.  Row 1: the products the backend multiplies, static — the whole
+    cube for ``jnp``, the stack capacity for the compacted backends."""
+    per_k, computed = [], 0
+    for a_mask, b_mask in calls:
+        ni, nk = a_mask.shape[-2:]
+        nj = b_mask.shape[-1]
+        if ni * nj >= 1 << 31:
+            raise ValueError(f"a {ni}x{nk}x{nj} block cube is past the "
+                             "int32 product counts")
+        cube = ni * nk * nj
+        n_calls = math.prod(a_mask.shape[:-2])
+        computed += n_calls * (cube if backend == "jnp"
+                               else resolve_capacity(stack_capacity, cube))
+        per_k.append((jnp.sum(a_mask, axis=-2, dtype=jnp.int32)
+                      * jnp.sum(b_mask, axis=-1, dtype=jnp.int32)).ravel())
+    per_k = jnp.concatenate(per_k)
+    if per_k.size >= 1 << (31 - _COUNT_BITS):
+        raise ValueError(f"{per_k.size} contracted blocks are past the "
+                         "int32 product counts")
+    lo = jnp.sum(per_k & _LO)
+    hi = jnp.sum(per_k >> _COUNT_BITS) + (lo >> _COUNT_BITS)
+    return jnp.stack([
+        jnp.stack([hi, lo & _LO]),
+        jnp.asarray([computed >> _COUNT_BITS, computed & _LO], jnp.int32),
+    ])

@@ -89,6 +89,7 @@ pattern, so repeated patterns cost neither a host walk nor a recompile
 """
 from __future__ import annotations
 
+import dataclasses
 from collections import OrderedDict
 from dataclasses import dataclass
 from functools import lru_cache
@@ -427,7 +428,6 @@ class CacheStats:
     hits: int = 0
     misses: int = 0
     builds: int = 0  # program constructions (lower/trace roots)
-    evictions: int = 0
     pattern_hits: int = 0  # compacted product-list reuse (same signature)
     pattern_misses: int = 0
     chain_hits: int = 0  # fused chain-step program reuse (sign iteration)
@@ -439,39 +439,11 @@ class CacheStats:
     transport_misses: int = 0  # resolutions that walked the masks
     transport_dense: int = 0  # fresh resolutions that chose dense panels
     transport_compressed: int = 0  # ... that chose compressed panels
-    assign_hits: int = 0  # block-assignment resolutions served from cache
-    assign_misses: int = 0  # resolutions that derived a permutation
     envelope_hits: int = 0  # chain-envelope forecasts served from cache
     envelope_misses: int = 0  # forecasts that ran the symbolic propagation
     dispatch_hits: int = 0  # serving-dispatch bucket lookups served warm
     dispatch_misses: int = 0  # ... that warmed a new bucket
     drift_retunes: int = 0  # pattern drift that forced a re-tune/re-derive
-
-    def as_dict(self) -> dict:
-        return {
-            "hits": self.hits,
-            "misses": self.misses,
-            "builds": self.builds,
-            "evictions": self.evictions,
-            "pattern_hits": self.pattern_hits,
-            "pattern_misses": self.pattern_misses,
-            "chain_hits": self.chain_hits,
-            "chain_misses": self.chain_misses,
-            "tuner_hits": self.tuner_hits,
-            "tuner_misses": self.tuner_misses,
-            "tuner_trials": self.tuner_trials,
-            "transport_hits": self.transport_hits,
-            "transport_misses": self.transport_misses,
-            "transport_dense": self.transport_dense,
-            "transport_compressed": self.transport_compressed,
-            "assign_hits": self.assign_hits,
-            "assign_misses": self.assign_misses,
-            "envelope_hits": self.envelope_hits,
-            "envelope_misses": self.envelope_misses,
-            "dispatch_hits": self.dispatch_hits,
-            "dispatch_misses": self.dispatch_misses,
-            "drift_retunes": self.drift_retunes,
-        }
 
 
 _CACHE_MAXSIZE = 128
@@ -497,7 +469,7 @@ def register_cache(clear_fn) -> None:
 
 def cache_stats() -> dict:
     """Program/pattern/chain/tuner-cache counters (hits / misses / ...)."""
-    return _stats.as_dict()
+    return dataclasses.asdict(_stats)
 
 
 def clear_cache() -> None:
@@ -515,16 +487,8 @@ def clear_cache() -> None:
     plan_multiply.cache_clear()
     for fn in _extra_caches:
         fn()
-    _stats.hits = _stats.misses = _stats.builds = _stats.evictions = 0
-    _stats.pattern_hits = _stats.pattern_misses = 0
-    _stats.chain_hits = _stats.chain_misses = 0
-    _stats.tuner_hits = _stats.tuner_misses = _stats.tuner_trials = 0
-    _stats.transport_hits = _stats.transport_misses = 0
-    _stats.transport_dense = _stats.transport_compressed = 0
-    _stats.assign_hits = _stats.assign_misses = 0
-    _stats.envelope_hits = _stats.envelope_misses = 0
-    _stats.dispatch_hits = _stats.dispatch_misses = 0
-    _stats.drift_retunes = 0
+    global _stats
+    _stats = CacheStats()
 
 
 # ---------------------------------------------------------------------------
@@ -564,7 +528,6 @@ def get_product_stacks(pair_ok):
     _pattern_cache[sig] = entry
     if len(_pattern_cache) > _CACHE_MAXSIZE:
         _pattern_cache.popitem(last=False)
-        _stats.evictions += 1
     return entry
 
 
@@ -611,7 +574,6 @@ def get_device_capacity(ok, mesh, engine: str) -> int:
     _bound_cache[key] = cap
     if len(_bound_cache) > _CACHE_MAXSIZE:
         _bound_cache.popitem(last=False)
-        _stats.evictions += 1
     return cap
 
 
@@ -664,7 +626,6 @@ def get_transport(
     _transport_cache[key] = tr
     if len(_transport_cache) > _CACHE_MAXSIZE:
         _transport_cache.popitem(last=False)
-        _stats.evictions += 1
     return tr
 
 
@@ -745,8 +706,7 @@ def get_assignment(mask_a, mask_b, mesh, mode: str):
     Derives the deterministic permutation of ``core.distribute`` from the
     concrete operand masks (``assignment_for`` on the integer mask
     product), LRU-cached on the pattern signatures so a repeated pattern
-    re-walks nothing; counted by the ``assign_*`` fields of
-    ``cache_stats()``.
+    re-walks nothing.
     """
     import numpy as np
 
@@ -762,15 +722,12 @@ def get_assignment(mask_a, mask_b, mesh, mode: str):
     )
     hit = _assign_cache.get(key)
     if hit is not None:
-        _stats.assign_hits += 1
         _assign_cache.move_to_end(key)
         return hit
-    _stats.assign_misses += 1
     asg = D.assignment_for(mode, D.product_counts(am, bm), (p_r, p_c))
     _assign_cache[key] = asg
     if len(_assign_cache) > _CACHE_MAXSIZE:
         _assign_cache.popitem(last=False)
-        _stats.evictions += 1
     return asg
 
 
@@ -868,7 +825,6 @@ def get_envelope(
     _envelope_cache[key] = env
     if len(_envelope_cache) > _CACHE_MAXSIZE:
         _envelope_cache.popitem(last=False)
-        _stats.evictions += 1
     return env
 
 
@@ -959,7 +915,6 @@ def get_local_compiled(
     _program_cache[key] = prog
     if len(_program_cache) > _CACHE_MAXSIZE:
         _program_cache.popitem(last=False)
-        _stats.evictions += 1
     return prog
 
 
@@ -1005,7 +960,9 @@ def build_shard_body(plan: MultiplyPlan, *, threshold: float, backend: str,
                      tile: tuple[int, int, int] | None = None,
                      interpret: bool | None = None, transport=None):
     """The engine's raw per-shard body: ``(ab, am, an, bb, bm, bn) ->
-    (cb, cm)`` on shards, no shard_map wrapper.
+    (cb, cm, calls)`` on shards, no shard_map wrapper, under the
+    ``spgemm.engine`` named scope.  ``calls`` are the operand masks of
+    the shard's local-stage calls (``local_mm.product_counts``).
 
     Iteration chains (``core/signiter.py``) inline this into ONE enclosing
     shard_map spanning a whole sweep — multiple multiplies plus the
@@ -1030,20 +987,29 @@ def build_shard_body(plan: MultiplyPlan, *, threshold: float, backend: str,
     if plan.kind == "ring":
         from repro.core.cannon import ring_body
 
-        return ring_body(plan, **kw)
-    if plan.kind == "pull":
+        body = ring_body(plan, **kw)
+    elif plan.kind == "pull":
         from repro.core.twofive import pull_body
 
-        return pull_body(plan, **kw)
-    if plan.kind == "stacked":
+        body = pull_body(plan, **kw)
+    elif plan.kind == "stacked":
         from repro.core.twofive import stacked_body
 
-        return stacked_body(plan, c_layout="2d", **kw)
-    if plan.kind == "gather":
+        body = stacked_body(plan, c_layout="2d", **kw)
+    elif plan.kind == "gather":
         from repro.core.gather import gather_body
 
-        return gather_body(plan, **kw)
-    raise ValueError(plan.kind)
+        body = gather_body(plan, **kw)
+    else:
+        raise ValueError(plan.kind)
+
+    def scoped(*shards):
+        import jax
+
+        with jax.named_scope("spgemm.engine"):
+            return body(*shards)
+
+    return scoped
 
 
 def get_compiled(
@@ -1190,7 +1156,6 @@ def get_compiled(
     _program_cache[key] = prog
     if len(_program_cache) > _CACHE_MAXSIZE:
         _program_cache.popitem(last=False)
-        _stats.evictions += 1
     return prog
 
 
@@ -1355,5 +1320,4 @@ def get_chain_compiled(key: tuple, builder):
     _program_cache[key] = prog
     if len(_program_cache) > _CACHE_MAXSIZE:
         _program_cache.popitem(last=False)
-        _stats.evictions += 1
     return prog

@@ -43,7 +43,8 @@ from repro.core import bsm as B
 from repro.core import plan as plan_mod
 from repro.core.bsm import block_norms
 from repro.core.engine import multiply
-from repro.core.local_mm import local_filtered_mm
+from repro.core.local_mm import count_value, local_filtered_mm, product_counts
+from repro.obs import span
 
 
 @dataclass
@@ -61,6 +62,12 @@ class SignIterStats:
     #   chain_misses delta (1 = whole chain ran one program), legacy =
     #   per-multiply program misses delta
     envelope: bool = False  # chain ran against a forecast pattern envelope
+    # fused: block products whose A and B blocks are both present,
+    # (X.X, X.Y) per sweep, summed over the mesh
+    products_present: list[tuple[int, int]] = field(default_factory=list)
+    # fused: block products the local stage multiplies per multiply
+    # (static: the cube for jnp, the stack capacity otherwise)
+    products_computed: int = 0
 
 
 def _scale_any(x, s):
@@ -109,24 +116,26 @@ def _make_sweep(mm, dtype, filter_eps: float, *, total_blocks: int,
     """One whole Newton-Schulz sweep as a single traceable function.
 
     ``mm(ab, am, an, bb, bm, bn) -> (cb, cm)`` is the multiply body — the
-    engine's raw per-shard body (``plan.build_shard_body``) when the sweep
-    runs inside one enclosing shard_map, or ``local_filtered_mm`` on a
-    single device.  Everything between the two multiplies is shard-local
-    algebra with incrementally-updated norms; the residual and occupancy
-    leave as device scalars via ``psum_axes`` all-reduces — never a gather
-    of the matrix.
+    engine's raw per-shard body (``plan.build_shard_body``, less the masks
+    ``_counted_sweep`` takes from it) when the sweep runs inside one
+    enclosing shard_map, or ``local_filtered_mm`` on a single device.
+    Everything between the two multiplies is shard-local algebra with
+    incrementally-updated norms; the residual and occupancy leave as
+    device scalars via ``psum_axes`` all-reduces — never a gather of the
+    matrix.
     """
     eps = float(filter_eps)
 
     def post_filter(cb, cm, cn):
         if eps <= 0.0:
             return cb, cm, cn
-        keep = cm & (cn > eps)
-        return (
-            cb * keep[:, :, None, None].astype(cb.dtype),
-            keep,
-            jnp.where(keep, cn, 0.0),
-        )
+        with jax.named_scope("signiter.filter"):
+            keep = cm & (cn > eps)
+            return (
+                cb * keep[:, :, None, None].astype(cb.dtype),
+                keep,
+                jnp.where(keep, cn, 0.0),
+            )
 
     def sweep(xb, xm, xn, ib, im):
         # X^2 (multiply 1) + post-filter, mirroring multiply(filter_eps=...)
@@ -145,18 +154,58 @@ def _make_sweep(mm, dtype, filter_eps: float, *, total_blocks: int,
         cn = cn * jnp.float32(0.5)
         # convergence: || X_{n+1} - X_n ||_F / || X_{n+1} ||_F — partial
         # sums per shard, all three scalars in ONE stacked all-reduce
-        diff = (cb - xb).astype(jnp.float32)
-        partials = jnp.stack([
-            jnp.sum(jnp.square(diff)),
-            jnp.sum(jnp.square(cn)),
-            jnp.sum(cm.astype(jnp.float32)),
-        ])
-        if psum_axes is not None:
-            partials = jax.lax.psum(partials, psum_axes)
-        num_sq, den_sq, occ_cnt = partials
-        residual = jnp.sqrt(num_sq) / jnp.maximum(jnp.sqrt(den_sq), 1e-30)
-        occupancy = occ_cnt / total_blocks
+        with jax.named_scope("signiter.residual"):
+            diff = (cb - xb).astype(jnp.float32)
+            partials = jnp.stack([
+                jnp.sum(jnp.square(diff)),
+                jnp.sum(jnp.square(cn)),
+                jnp.sum(cm.astype(jnp.float32)),
+            ])
+            if psum_axes is not None:
+                partials = jax.lax.psum(partials, psum_axes)
+            num_sq, den_sq, occ_cnt = partials
+            residual = jnp.sqrt(num_sq) / jnp.maximum(jnp.sqrt(den_sq),
+                                                      1e-30)
+            occupancy = occ_cnt / total_blocks
         return cb, cm, cn, residual, occupancy
+
+    return sweep
+
+
+def _counted_sweep(mm, dtype, filter_eps: float, *, total_blocks: int,
+                   psum_axes=None, count_axes=None, backend: str = "jnp",
+                   stack_capacity: int | None = None):
+    """``_make_sweep``'s sweep with the block products of its two
+    multiplies as a sixth output.  ``mm`` returns ``(cb, cm, calls)``,
+    ``calls`` the operand masks of its local-stage calls (the engine's
+    shard body); the sweep counts them (``local_mm.product_counts``) into
+    an int32 (2, 2, 2) array — multiply, present / computed, hi / lo —
+    summed over ``count_axes`` in one all-reduce."""
+    calls = []  # filled while the sweep traces: one entry per multiply
+
+    def mm_recorded(*args):
+        cb, cm, c = mm(*args)
+        calls.append(c)
+        return cb, cm
+
+    inner = _make_sweep(mm_recorded, dtype, filter_eps,
+                        total_blocks=total_blocks, psum_axes=psum_axes)
+
+    def sweep(xb, xm, xn, ib, im):
+        calls.clear()
+        out = inner(xb, xm, xn, ib, im)
+        # count after the residual: counting, or reducing the counts,
+        # between the multiplies moves XLA's schedule and memory
+        # placement of the local stage (one einsum of the 2x2 twofive
+        # sweep at nb 512 ran 4% slower on a v5e) and makes every device
+        # wait for the slowest there
+        recorded, _ = jax.lax.optimization_barrier((list(calls), out[3]))
+        products = jnp.stack([
+            product_counts(c, backend=backend, stack_capacity=stack_capacity)
+            for c in recorded])
+        if count_axes is not None:
+            products = jax.lax.psum(products, count_axes)
+        return out + (products,)
 
     return sweep
 
@@ -194,6 +243,10 @@ def get_sweep_program(
     """The compiled fused sweep for (mesh, shape, engine, backend, ...),
     cached in the plan layer's program cache (``plan.get_chain_compiled``,
     counted by ``chain_hits``/``chain_misses``).
+
+    The program maps ``(xb, xm, xn, ib, im)`` to ``(xb, xm, xn, residual,
+    occupancy, products)``; ``products`` are the block-product counts of
+    the sweep's two multiplies (``_counted_sweep``).
 
     ``mesh=None`` builds the single-device sweep around
     ``local_filtered_mm``.  Otherwise the WHOLE sweep is one shard_map
@@ -285,11 +338,14 @@ def get_sweep_program(
         if mesh is None:
             local_kw = {k: v for k, v in mm_kw.items() if k != "transport"}
 
-            def mm(*args):
-                return local_filtered_mm(*args, **local_kw)
+            def mm(ab, am, an, bb, bm, bn):
+                cb, cm = local_filtered_mm(ab, am, an, bb, bm, bn,
+                                           **local_kw)
+                return cb, cm, [(am, bm)]
 
-            return jax.jit(_make_sweep(mm, x.dtype, filter_eps,
-                                       total_blocks=total_blocks))
+            return jax.jit(_counted_sweep(
+                mm, x.dtype, filter_eps, total_blocks=total_blocks,
+                backend=backend, stack_capacity=stack_capacity))
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
@@ -298,8 +354,12 @@ def get_sweep_program(
         # transport=None -> dense inside build_shard_body (chain-safe);
         # an envelope-resolved PanelTransport rides through untouched
         mm = plan_mod.build_shard_body(plan, **mm_kw)
-        sweep = _make_sweep(mm, x.dtype, filter_eps,
-                            total_blocks=total_blocks, psum_axes=("r", "c"))
+        sweep = _counted_sweep(mm, x.dtype, filter_eps,
+                               total_blocks=total_blocks,
+                               psum_axes=("r", "c"),
+                               count_axes=tuple(mesh.axis_names),
+                               backend=backend,
+                               stack_capacity=stack_capacity)
         blk = P("r", "c", None, None)
         m2 = P("r", "c")
         fn = shard_map(
@@ -309,7 +369,7 @@ def get_sweep_program(
             # (oracle-tested outputs; pallas bodies carry no vma)
             check_vma=False,
             in_specs=(blk, m2, m2, blk, m2),
-            out_specs=(blk, m2, m2, P(), P()),
+            out_specs=(blk, m2, m2, P(), P(), P()),
         )
         return jax.jit(fn)
 
@@ -609,32 +669,48 @@ def sign_iteration(
     ib, im = ident.blocks, ident.mask
     occ_trace: list[float] = []
     res_trace: list[float] = []
+    present: list[tuple[int, int]] = []
+    computed = 0
     pending: list[tuple] = []
     converged = False
     syncs = 0
     it = 0
-    for it in range(1, max_iter + 1):
-        # fetched per sweep: the chain counters in plan.cache_stats() then
-        # record how many sweeps of this iteration reused one program
-        sweep = get_sweep_program(
-            x, mesh, engine=engine, threshold=threshold,
-            filter_eps=filter_eps, backend=backend, l=l,
-            stack_capacity=stack_capacity, tile=tile, interpret=interpret,
-            envelope=env, transport=transport,
+    with span("signiter.chain") as chain:
+        for it in range(1, max_iter + 1):
+            with span("signiter.dispatch"):
+                # fetched per sweep: the chain counters in
+                # plan.cache_stats() then record how many sweeps of this
+                # iteration reused one program
+                sweep = get_sweep_program(
+                    x, mesh, engine=engine, threshold=threshold,
+                    filter_eps=filter_eps, backend=backend, l=l,
+                    stack_capacity=stack_capacity, tile=tile,
+                    interpret=interpret, envelope=env, transport=transport,
+                )
+                xb, xm, xn, res_d, occ_d, prod_d = sweep(xb, xm, xn, ib, im)
+            pending.append((res_d, occ_d, prod_d))
+            if it % sync_every == 0 or it == max_iter:
+                syncs += 1
+                with span("signiter.sync"):
+                    fetched = jax.device_get(pending)
+                for res, occ, prod in fetched:
+                    r = float(res)
+                    res_trace.append(r)
+                    occ_trace.append(float(occ))
+                    present.append((count_value(prod[0, 0]),
+                                    count_value(prod[1, 0])))
+                    computed = count_value(prod[0, 1])
+                    if r < tol:
+                        converged = True
+                pending = []
+                if converged:
+                    break
+        chain.counts.update(
+            sweeps=it, host_syncs=syncs,
+            products_present=sum(map(sum, present)),
+            products_computed=2 * it * computed,
+            block_flops=2 * x.bs_r * x.bs_c * x.bs_c,
         )
-        xb, xm, xn, res_d, occ_d = sweep(xb, xm, xn, ib, im)
-        pending.append((res_d, occ_d))
-        if it % sync_every == 0 or it == max_iter:
-            syncs += 1
-            for res_d, occ_d in pending:
-                r = float(res_d)
-                res_trace.append(r)
-                occ_trace.append(float(occ_d))
-                if r < tol:
-                    converged = True
-            pending = []
-            if converged:
-                break
 
     if mesh is not None:
         out = B.ShardedBSM(blocks=xb, mask=xm, norms=xn, mesh=mesh,
@@ -654,6 +730,8 @@ def sign_iteration(
         host_syncs=syncs,
         retraces=plan_mod.cache_stats()["chain_misses"] - chain_misses0,
         envelope=env is not None,
+        products_present=present,
+        products_computed=computed,
     )
     return result, stats
 

@@ -238,7 +238,8 @@ def ingest(tr: PanelTransport, capacity: int, blocks, mask):
 def permute(state, axes, pairs):
     """One transport hop: permute both wire arrays (mode-independent —
     dense state is (blocks, mask), compressed is (packed, idx1))."""
-    return tuple(lax.ppermute(x, axes, list(pairs)) for x in state)
+    with jax.named_scope("spgemm.transport"):
+        return tuple(lax.ppermute(x, axes, list(pairs)) for x in state)
 
 
 def dense_view(tr: PanelTransport, state, nr: int, nc: int, dtype=None):
@@ -268,39 +269,40 @@ def all_gather_panels(
     row/column panel — still a single fused collective pair, but the
     gathered bytes scale with occupancy.
     """
-    dtype = blocks.dtype  # widen wire-cast blocks back after the gather
-    if not tr.compressed:
-        gb = lax.all_gather(
-            _to_wire(tr, blocks), axis_name, axis=axis, tiled=True
+    with jax.named_scope("spgemm.transport"):
+        dtype = blocks.dtype  # widen wire-cast blocks back after the gather
+        if not tr.compressed:
+            gb = lax.all_gather(
+                _to_wire(tr, blocks), axis_name, axis=axis, tiled=True
+            )
+            gm = lax.all_gather(mask, axis_name, axis=axis, tiled=True)
+            return gb.astype(dtype), gm
+        nr, nc = mask.shape
+        packed, idx1 = pack_panel(blocks, mask, capacity)
+        packed = _to_wire(tr, packed)
+        ps = lax.all_gather(packed, axis_name, axis=0, tiled=False)
+        ix = lax.all_gather(idx1, axis_name, axis=0, tiled=False)
+        p = ps.shape[0]
+        valid = ix > 0
+        loc = jnp.where(valid, ix - 1, 0)
+        r, c = loc // nc, loc % nc
+        src = jnp.arange(p, dtype=jnp.int32)[:, None]
+        if axis == 1:  # A row panel: source s owns columns [s*nc, (s+1)*nc)
+            gf = r * (p * nc) + src * nc + c
+            out_r, out_c = nr, p * nc
+        elif axis == 0:  # B column panel: source s owns rows [s*nr, (s+1)*nr)
+            gf = (src * nr + r) * nc + c
+            out_r, out_c = p * nr, nc
+        else:
+            raise ValueError(f"gather axis must be 0 or 1, got {axis}")
+        guarded = ps * valid[..., None, None].astype(ps.dtype)
+        flatb = jnp.zeros((out_r * out_c,) + ps.shape[2:], ps.dtype)
+        flatb = flatb.at[gf.ravel()].add(
+            guarded.reshape((-1,) + ps.shape[2:])
         )
-        gm = lax.all_gather(mask, axis_name, axis=axis, tiled=True)
-        return gb.astype(dtype), gm
-    nr, nc = mask.shape
-    packed, idx1 = pack_panel(blocks, mask, capacity)
-    packed = _to_wire(tr, packed)
-    ps = lax.all_gather(packed, axis_name, axis=0, tiled=False)
-    ix = lax.all_gather(idx1, axis_name, axis=0, tiled=False)
-    p = ps.shape[0]
-    valid = ix > 0
-    loc = jnp.where(valid, ix - 1, 0)
-    r, c = loc // nc, loc % nc
-    src = jnp.arange(p, dtype=jnp.int32)[:, None]
-    if axis == 1:  # A row panel: source s owns columns [s*nc, (s+1)*nc)
-        gf = r * (p * nc) + src * nc + c
-        out_r, out_c = nr, p * nc
-    elif axis == 0:  # B column panel: source s owns rows [s*nr, (s+1)*nr)
-        gf = (src * nr + r) * nc + c
-        out_r, out_c = p * nr, nc
-    else:
-        raise ValueError(f"gather axis must be 0 or 1, got {axis}")
-    guarded = ps * valid[..., None, None].astype(ps.dtype)
-    flatb = jnp.zeros((out_r * out_c,) + ps.shape[2:], ps.dtype)
-    flatb = flatb.at[gf.ravel()].add(
-        guarded.reshape((-1,) + ps.shape[2:])
-    )
-    gm = jnp.zeros((out_r * out_c,), bool).at[gf.ravel()].max(valid.ravel())
-    out = flatb.reshape((out_r, out_c) + ps.shape[2:]).astype(dtype)
-    return out, gm.reshape(out_r, out_c)
+        gm = jnp.zeros((out_r * out_c,), bool).at[gf.ravel()].max(valid.ravel())
+        out = flatb.reshape((out_r, out_c) + ps.shape[2:]).astype(dtype)
+        return out, gm.reshape(out_r, out_c)
 
 
 # ---------------------------------------------------------------------------

@@ -39,6 +39,7 @@ commvolume.plan_volume, which also models the compressed wire format).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
@@ -58,9 +59,11 @@ def pull_body(
     interpret: bool | None = None,
     transport: T.PanelTransport = T.DENSE,
 ):
-    """The per-shard Algorithm-2 pull body (shards in, C shard out);
-    exposed so iteration chains can inline it into one enclosing
-    shard_map (``core/signiter.py``)."""
+    """The per-shard Algorithm-2 pull body: shards in, ``(cb, cm,
+    calls)`` out, the C shard and the operand masks of this shard's
+    local-stage calls (for ``local_mm.product_counts``); exposed so
+    iteration chains can inline it into one enclosing shard_map
+    (``core/signiter.py``)."""
     mm_kw = dict(
         threshold=threshold, backend=backend,
         stack_capacity=stack_capacity, tile=tile, interpret=interpret,
@@ -118,6 +121,7 @@ def pull_body(
             for _ in range(depth)
         ]
         c_msk = [jnp.zeros((nr, nc), bool) for _ in range(depth)]
+        calls = []
 
         # pipelined groups: group g+1's pulls are issued before group g's
         # pairwise products consume the current panels (rget overlap, §4)
@@ -138,10 +142,11 @@ def pull_body(
                     )
                     c_blk[t] = c_blk[t] + dcb
                     c_msk[t] = c_msk[t] | dcm
+                    calls.append((pam, pbm))
             cur = nxt
 
         if depth == 1:
-            return c_blk[0], c_msk[0]
+            return c_blk[0], c_msk[0], calls
 
         # ---- the L-1 partial-C sends to the panel owners -----------------
         i = lax.axis_index("r")
@@ -153,15 +158,16 @@ def pull_body(
         total_m = jnp.take(stack_m, lay, axis=0)
         for d, perm in enumerate(plan.c_rounds, start=1):
             t_send = (lay + d) % depth
-            rb = lax.ppermute(
-                jnp.take(stack_b, t_send, axis=0), axes, list(perm)
-            )
-            rm = lax.ppermute(
-                jnp.take(stack_m, t_send, axis=0), axes, list(perm)
-            )
+            with jax.named_scope("spgemm.transport"):
+                rb = lax.ppermute(
+                    jnp.take(stack_b, t_send, axis=0), axes, list(perm)
+                )
+                rm = lax.ppermute(
+                    jnp.take(stack_m, t_send, axis=0), axes, list(perm)
+                )
             total_b = total_b + rb
             total_m = total_m | rm
-        return total_b, total_m
+        return total_b, total_m, calls
 
     return body
 
@@ -170,8 +176,9 @@ def pull_executor(plan, **kw):
     """Algorithm 2 as static pulls on the 2D (r, c) mesh (any valid grid)."""
     blk = P("r", "c", None, None)
     m2 = P("r", "c")
+    body = pull_body(plan, **kw)
     return shard_map(
-        pull_body(plan, **kw),
+        lambda *shards: body(*shards)[:2],
         mesh=plan.mesh,
         # check_vma=False: the pallas backend's pallas_call builds plain
         # ShapeDtypeStructs (no vma annotation); engine outputs are
@@ -194,8 +201,10 @@ def stacked_body(
     transport: T.PanelTransport = T.DENSE,
 ):
     """The per-shard (l, r, c)-mesh 2.5D body (exposed for chain fusion,
-    like ``pull_body``); with c_layout="2d" the returned C shard is
-    replicated over ``l``, so chained multiplies compose."""
+    like ``pull_body``, and returning ``(cb, cm, calls)`` like it, the
+    ticks of the scanned ring stacked on a leading axis); with
+    c_layout="2d" the returned C shard is replicated over ``l``, so
+    chained multiplies compose."""
     ticks = plan.ticks
     groups = tuple(plan.layer_groups)
     uneven = len(set(groups)) > 1
@@ -222,11 +231,14 @@ def stacked_body(
                 yb, ym, T.panel_norms(yb, threshold), **mm_kw,
             )
             if uneven:
-                # mask ticks past this layer's k-chunk (uneven-L support)
+                # mask ticks past this layer's k-chunk (uneven-L support);
+                # a masked tick still computes its cube, but has no
+                # products present
                 active = t < my_groups
                 dcb = dcb * active.astype(dcb.dtype)
                 dcm = dcm & active
-            return cb + dcb, cm | dcm
+                xm = xm & active
+            return cb + dcb, cm | dcm, (xm, ym)
 
         # pre-shift with per-layer chunk offset: A_ij <- A_{i, j+i+start_l},
         # B_ij <- B_{i+j+start_l, j}; one static flattened permutation.
@@ -240,8 +252,10 @@ def stacked_body(
         cb = lax.pcast(cb, axes, to="varying")
         cm = lax.pcast(cm, axes, to="varying")
 
+        calls = []
         if ticks == 1:
-            cb, cm = compute(pa, pb, cb, cm, jnp.asarray(0, jnp.int32))
+            cb, cm, call = compute(pa, pb, cb, cm, jnp.asarray(0, jnp.int32))
+            calls.append(call)
         else:
             # double-buffered ring: the hop for tick t+1 is in flight
             # before the GEMM of tick t (see cannon.ring_body)
@@ -252,27 +266,32 @@ def stacked_body(
                 pa, pb, na, nb_, cb, cm = carry
                 fa = T.permute(na, "c", plan.shift_a)
                 fb = T.permute(nb_, "r", plan.shift_b)
-                cb, cm = compute(pa, pb, cb, cm, t)
-                return (na, nb_, fa, fb, cb, cm), None
+                cb, cm, call = compute(pa, pb, cb, cm, t)
+                return (na, nb_, fa, fb, cb, cm), call
 
             if ticks > 2:
-                (pa, pb, na, nb_, cb, cm), _ = lax.scan(
+                (pa, pb, na, nb_, cb, cm), scanned = lax.scan(
                     tick, (pa, pb, na, nb_, cb, cm),
                     jnp.arange(ticks - 2, dtype=jnp.int32),
                 )
+                calls.append(scanned)
             # last two ticks: compute only, no trailing shift
-            cb, cm = compute(pa, pb, cb, cm,
-                             jnp.asarray(ticks - 2, jnp.int32))
-            cb, cm = compute(na, nb_, cb, cm,
-                             jnp.asarray(ticks - 1, jnp.int32))
+            cb, cm, call = compute(pa, pb, cb, cm,
+                                   jnp.asarray(ticks - 2, jnp.int32))
+            calls.append(call)
+            cb, cm, call = compute(na, nb_, cb, cm,
+                                   jnp.asarray(ticks - 1, jnp.int32))
+            calls.append(call)
 
         # --- partial-C reduction over the depth axis (the L-1 sends)
         cmi = cm.astype(jnp.int32)
-        if c_layout == "2d":
-            return lax.psum(cb, "l"), lax.psum(cmi, "l") > 0
-        cb = lax.psum_scatter(cb, "l", scatter_dimension=0, tiled=True)
-        cmi = lax.psum_scatter(cmi, "l", scatter_dimension=0, tiled=True)
-        return cb, cmi > 0
+        with jax.named_scope("spgemm.transport"):
+            if c_layout == "2d":
+                return lax.psum(cb, "l"), lax.psum(cmi, "l") > 0, calls
+            cb = lax.psum_scatter(cb, "l", scatter_dimension=0, tiled=True)
+            cmi = lax.psum_scatter(cmi, "l", scatter_dimension=0,
+                                   tiled=True)
+        return cb, cmi > 0, calls
 
     return body
 
@@ -296,8 +315,9 @@ def stacked_executor(plan, *, c_layout: str = "2d", **kw):
         blk_out, m2_out = P(("r", "l"), "c", None, None), P(("r", "l"), "c")
     else:
         raise ValueError(f"unknown c_layout {c_layout!r}")
+    body = stacked_body(plan, c_layout=c_layout, **kw)
     return shard_map(
-        stacked_body(plan, c_layout=c_layout, **kw),
+        lambda *shards: body(*shards)[:2],
         mesh=plan.mesh,
         # check_vma=False: the pallas backend's pallas_call builds plain
         # ShapeDtypeStructs (no vma annotation); engine outputs are

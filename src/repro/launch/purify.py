@@ -11,7 +11,11 @@ entirely device-resident — the fused sign-iteration engine of
 ``core/signiter.py`` (DESIGN.md §5).  After the first purification every
 later one is pure cache: the chain-step program, the multiply plan and
 the jit executable are all reused (``plan.cache_stats()`` is printed per
-repeat; ``builds`` must stay flat).
+repeat; ``builds`` must stay flat).  Each repeat also prints the share of
+the block products the local stage multiplied whose A and B blocks were
+both present, and the host seconds the chain spent enqueueing sweeps
+(``signiter.dispatch``) and waiting for their residuals
+(``signiter.sync``), read from the program's spans (``repro.obs``).
 
 Engine selection is autotuned (DESIGN.md §6): with ``--tuning-db`` the
 driver runs ``engine="auto"`` — the pattern-aware tuner picks (engine, L)
@@ -98,7 +102,7 @@ def run(argv=None) -> PurifyRun:
 
     import jax
 
-    from repro import tuner
+    from repro import obs, tuner
     from repro.core import bsm as B
     from repro.core import plan as plan_mod
     from repro.core.signiter import density_matrix, trace
@@ -158,6 +162,12 @@ def run(argv=None) -> PurifyRun:
               f"chain {cache['chain_hits']}h/{cache['chain_misses']}m "
               f"tuner {cache['tuner_hits']}h/{cache['tuner_misses']}m/"
               f"{cache['tuner_trials']}t")
+        host = _chain_host_seconds(obs.records())
+        computed = 2 * stats.iterations * stats.products_computed
+        useful = sum(map(sum, stats.products_present)) / max(computed, 1)
+        print(f"    useful products {useful:.2%}, host "
+              f"dispatch {host['signiter.dispatch']:.4f}s "
+              f"sync {host['signiter.sync']:.4f}s")
     final = plan_mod.cache_stats()
     # the chain program is compiled exactly once; program builds beyond it
     # can only come from the tuner's measured trials (cold DB), never from
@@ -174,6 +184,17 @@ def run(argv=None) -> PurifyRun:
         print(f"tuning db: {len(db)} record(s) at {db.path}")
     return PurifyRun(h=h_dev, p=p, stats=all_stats, seconds=seconds,
                      mesh=mesh)
+
+
+def _chain_host_seconds(records) -> dict:
+    """Seconds in ``signiter.dispatch`` and ``signiter.sync`` spans of
+    the last ``signiter.chain`` span among ``records``."""
+    chain = next(r for r in reversed(records) if r.name == "signiter.chain")
+    out = {"signiter.dispatch": 0.0, "signiter.sync": 0.0}
+    for r in records:
+        if r.parent == chain.id and r.name in out:
+            out[r.name] += r.seconds
+    return out
 
 
 if __name__ == "__main__":

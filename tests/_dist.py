@@ -1144,7 +1144,42 @@ def check_pipeline():
     print("pipeline OK")
 
 
+def check_product_counts():
+    """The fused sweep's block-product counts on several devices equal
+    the count from the operand masks for every multiply, and repeat
+    exactly: twofive and cannon on a 2x2 mesh, cannon's scanned ring on
+    4x4, twofive on a stacked 2x2x2 mesh and on an uneven 2x3x3 one.
+    Summed over the mesh, each engine's local stage multiplies the whole
+    cube once per multiply (jnp backend), plus the cubes of the uneven
+    mesh's masked ticks; the compiled sweep's permutes are named
+    ``spgemm.transport``."""
+    from repro.core.signiter import lower_sweep, sign_iteration
+    from repro.launch.mesh import make_spgemm_mesh
+    from tests.test_signiter import banded_hamiltonian, mask_chain_products
+
+    nb = 12
+    h, mask = banded_hamiltonian(nb=nb)
+    want = mask_chain_products(mask, 5)
+    for engine, kw, cubes in (("twofive", {"p": 2}, 1), ("cannon", {"p": 2}, 1),
+                              ("cannon", {"p": 4}, 1), ("twofive", {"p": 2, "l": 2}, 1),
+                              ("twofive", {"p": 3, "l": 2}, 4 / 3)):
+        mesh = make_spgemm_mesh(**kw)
+        runs = [sign_iteration(h, mesh=mesh, engine=engine, max_iter=5,
+                               tol=0.0, sync_every=2)[1] for _ in range(2)]
+        for st in runs:
+            assert st.products_present == want, (engine, kw, st.products_present)
+            assert st.products_computed == cubes * nb ** 3, (
+                engine, kw, st.products_computed)
+            assert st.host_syncs == 3
+        hlo = lower_sweep(mesh, nb, 4, engine=engine).compile().as_text()
+        permutes = [ln for ln in hlo.splitlines()
+                    if "collective-permute" in ln and "op_name=" in ln]
+        assert permutes and all("spgemm.transport" in ln for ln in permutes), engine
+    print("product_counts OK")
+
+
 CHECKS = {
+    "product_counts": check_product_counts,
     "engines": check_engines,
     "transport": check_transport,
     "stacks_backends": check_stacks_backends,

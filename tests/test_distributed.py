@@ -76,6 +76,11 @@ def test_signiter_sharded_device_resident():
     assert "signiter_sharded OK" in out
 
 
+def test_sweep_product_counts_on_four_devices():
+    out = _run("product_counts")
+    assert "product_counts OK" in out
+
+
 def test_envelope_chain_sharded():
     """Envelope-compiled drifting-pattern chains on a mesh: builds == 1,
     bitwise == the chain-safe fused chain, compressed transport unlocked,

@@ -179,3 +179,88 @@ def test_sign_iteration_storage_dtype_matrix():
     err = np.abs(np.asarray(s.to_dense(), np.float64)
                  - np.asarray(s32.to_dense(), np.float64)).max()
     assert err <= {"float32": 1e-5, "bfloat16": 7e-2}[dt], (dt, err)
+
+
+# ---------------------------------------------------------------------------
+# block-product counts of the fused sweep
+# ---------------------------------------------------------------------------
+
+
+def banded_hamiltonian(nb: int = 16, bs: int = 4, seed: int = 0):
+    """A symmetric block-tridiagonal H: X fills in by one band per
+    multiply, so a short chain stays sparse."""
+    rng = np.random.default_rng(seed)
+    idx = np.arange(nb)
+    mask = np.abs(idx[:, None] - idx[None, :]) <= 1
+    dense = rng.standard_normal((nb * bs, nb * bs)).astype(np.float32)
+    dense = dense + dense.T
+    blocks = dense.reshape(nb, bs, nb, bs).transpose(0, 2, 1, 3)
+    return B.make_bsm(jnp.asarray(blocks), jnp.asarray(mask)), mask
+
+
+def mask_chain_products(mask, sweeps: int) -> list[tuple[int, int]]:
+    """(X.X, X.Y) block products with both blocks present, per sweep, of
+    an unfiltered chain from X's mask alone (``benchlib.work``'s count:
+    sum_k colcount_A(k) * rowcount_B(k))."""
+    def present(a, b):
+        return int(a.sum(axis=0, dtype=np.int64) @ b.sum(axis=1,
+                                                       dtype=np.int64))
+
+    x = np.asarray(mask, bool)
+    eye = np.eye(x.shape[0], dtype=bool)
+    out = []
+    for _ in range(sweeps):
+        x2 = (x.astype(np.int64) @ x.astype(np.int64)) > 0
+        y = eye | x2
+        out.append((present(x, x), present(x, y)))
+        x = (x.astype(np.int64) @ y.astype(np.int64)) > 0
+    return out
+
+
+def test_sweep_counts_products_present_exactly():
+    """The fused sweep's ``products_present`` equals the count from the
+    operand masks for every multiply, repeats exactly, and takes no host
+    sync of its own; ``products_computed`` is the jnp backend's cube."""
+    h, mask = banded_hamiltonian()
+    runs = [sign_iteration(h, max_iter=5, tol=0.0, sync_every=2)[1]
+            for _ in range(2)]
+    st = runs[0]
+    assert st.products_present == mask_chain_products(mask, 5)
+    assert runs[1].products_present == st.products_present
+    assert st.products_computed == 16 ** 3
+    assert st.host_syncs == 3  # sweeps 2, 4 and the last: unchanged
+    assert len(st.residual_trace) == len(st.products_present) == 5
+
+
+def test_chain_span_carries_the_counts():
+    from repro import obs
+
+    h, mask = banded_hamiltonian()
+    _, st = sign_iteration(h, max_iter=3, tol=0.0, sync_every=2)
+    chain = [r for r in obs.records() if r.name == "signiter.chain"][-1]
+    kids = [r for r in obs.records() if r.parent == chain.id]
+    assert chain.counts == {
+        "sweeps": 3, "host_syncs": 2,
+        "products_present": sum(map(sum, mask_chain_products(mask, 3))),
+        "products_computed": 2 * 3 * 16 ** 3, "block_flops": 2 * 4 ** 3,
+    }
+    assert [r.name for r in kids].count("signiter.dispatch") == 3
+    assert [r.name for r in kids].count("signiter.sync") == 2
+
+
+def test_sweep_hlo_names_its_layers():
+    """The compiled sweep's op metadata names the layer of each op: the
+    local stage's dot under ``spgemm.local``, the engine's panel permutes
+    under ``spgemm.transport``."""
+    from repro.core.signiter import lower_sweep
+    from repro.launch.mesh import make_spgemm_mesh
+
+    hlo = lower_sweep(make_spgemm_mesh(p=1), 8, 4).compile().as_text()
+    lines = hlo.splitlines()
+    dots = [ln for ln in lines if " dot(" in ln]
+    permutes = [ln for ln in lines if "collective-permute" in ln
+                and "op_name=" in ln]
+    assert dots and all("spgemm.local" in ln for ln in dots)
+    assert permutes and all("spgemm.transport" in ln for ln in permutes)
+    assert "spgemm.engine" in hlo
+    assert "signiter.residual" in hlo
