@@ -3,11 +3,15 @@
 This is DBCSR's "batched small-block GEMM with on-the-fly filtering" stage
 (handled by LIBXSMM / GPU kernels in the paper).  Three implementations:
 
-* ``jnp`` — a masked einsum oracle.  The (i,k,j) product is included only if
+* ``jnp`` — a dense einsum.  The (i,k,j) product is included only if
   both blocks are occupied AND ``norm(A_ik)*norm(B_kj) > threshold`` — the
   paper's on-the-fly filter.  Runs everywhere; FLOPs are *not* skipped (the
   einsum contracts the full cube) but the semantics are exact.  Right for
-  high fill, where dense MXU work beats gather/scatter overhead.
+  high fill, where dense MXU work beats gather/scatter overhead.  Above a
+  zero threshold the filter couples i, k and j, so the einsum is weighted
+  by the float (ni, nk, nj) filter cube; at threshold 0 the filter is the
+  outer AND of the two masks and ``separable_mm`` zeroes the absent
+  blocks and contracts the plain 4-D product, with no cube.
 * ``stacks`` — DBCSR's stack design (DESIGN.md §2): compact the filter cube
   into a padded product list (``kernels/stacks.py``), gather the surviving
   A/B blocks, run ONE batched ``dot_general`` over the list, segment-sum
@@ -316,6 +320,35 @@ def stacks_mm(
     return c[: ni * nj].reshape(ni, nj, bs_r, bs_c).astype(dtype)
 
 
+def separable_mm(
+    a_blocks: jax.Array,
+    a_mask: jax.Array,
+    b_blocks: jax.Array,
+    b_mask: jax.Array,
+    *,
+    precision=jax.lax.Precision.HIGHEST,
+) -> tuple[jax.Array, jax.Array]:
+    """(mA ⊙ A)(mB ⊙ B): the cube-weighted einsum of the ``jnp`` backend
+    under the threshold-0 filter, whose cube is the outer AND of the
+    masks, without building the cube.
+
+    Blocks under a false mask entry are zeroed, so their data is ignored
+    as the cube would ignore it; the 4-D contraction keeps the block axes
+    apart (a reshape to (N, N) doubles XLA's temporaries).  The C mask is
+    ``any_k mA_ik & mB_kj``, from a 0/1 product whose terms are all
+    nonnegative, so ``> 0`` is exact.
+    """
+    zero = jnp.zeros((), jnp.float32)
+    a = jnp.where(a_mask[:, :, None, None], a_blocks.astype(jnp.float32), zero)
+    b = jnp.where(b_mask[:, :, None, None], b_blocks.astype(jnp.float32), zero)
+    c_blocks = jnp.einsum(
+        "ikab,kjbc->ijac", a, b, precision=precision
+    ).astype(a_blocks.dtype)
+    c_mask = jnp.dot(a_mask.astype(jnp.float32),
+                     b_mask.astype(jnp.float32)) > 0
+    return c_blocks, c_mask
+
+
 def local_filtered_mm(
     a_blocks: jax.Array,
     a_mask: jax.Array,
@@ -342,9 +375,15 @@ def local_filtered_mm(
     kernel's MXU sub-tile shape (ignored elsewhere).  ``interpret``
     controls the pallas backend only: None auto-detects the platform
     (compiled Mosaic on TPU, interpreter elsewhere — see
-    ``repro.config.pallas_interpret``).
+    ``repro.config.pallas_interpret``).  ``jnp`` at a threshold of 0 runs
+    ``separable_mm`` under the nested ``separable`` scope, so a device
+    trace shows which form ran.
     """
     with jax.named_scope("spgemm.local"):
+        if backend == "jnp" and not threshold > 0.0:
+            with jax.named_scope("separable"):
+                return separable_mm(a_blocks, a_mask, b_blocks, b_mask,
+                                    precision=precision)
         ni, nk = a_blocks.shape[:2]
         nj = b_blocks.shape[1]
         ok = pair_filter(a_mask, a_norms, b_mask, b_norms, threshold)

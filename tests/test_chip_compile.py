@@ -109,3 +109,44 @@ def test_cannon_sweep_compiles_on_2x2_mesh(topo):
                            filter_eps=1e-9).compile()
     assert "collective-permute" in compiled.as_text()
     assert _bytes(compiled) < HBM_BYTES / 2
+
+
+# temp bytes of the dense multiply (nb 512, bs 32) when its local stage
+# weighted the einsum by the float filter cube (compile for v5e)
+MASKED_DENSE_TEMP_BYTES = 2_684_419_072
+
+
+def test_dense_multiply_local_stage_builds_no_filter_cube(one_chip):
+    """At threshold 0 the single-device multiply contracts the masked
+    blocks directly: no (512, 512, 512) f32 cube, no more temporaries than
+    the cube-weighted form, the contraction under ``spgemm.local/separable``."""
+    from repro.core.bsm import BlockSparseMatrix
+    from repro.core.engine import _multiply_reference_jit
+
+    nb, bs = 512, 32
+    m = BlockSparseMatrix(
+        jax.ShapeDtypeStruct((nb, nb, bs, bs), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((nb, nb), jnp.bool_, sharding=one_chip),
+        jax.ShapeDtypeStruct((nb, nb), jnp.float32, sharding=one_chip),
+    )
+    compiled = _multiply_reference_jit.lower(m, m, 0.0, "jnp").compile()
+    text = compiled.as_text()
+    assert "f32[512,512,512]" not in text
+    assert "spgemm.local/separable/ikab,kjbc->ijac" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= MASKED_DENSE_TEMP_BYTES, temp
+
+
+def test_h2o_sweep_keeps_the_masked_local_stage(topo):
+    """Above threshold 0 the filter couples i, k and j: the one-chip H2O
+    sweep (nb 384, bs 23, threshold 1e-6) still weights its einsums by
+    the (384, 384, 384) filter cube."""
+    from repro.core.signiter import lower_sweep
+    from repro.launch.mesh import make_spgemm_mesh
+
+    mesh = make_spgemm_mesh(p=1, devices=topo.devices[:1])
+    text = lower_sweep(mesh, 384, 23, engine="twofive", threshold=1e-6,
+                       filter_eps=1e-6).compile().as_text()
+    assert "f32[384,384,384]" in text
+    assert "spgemm.local/ikj,ikab,kjbc->ijac" in text
+    assert "spgemm.local/separable" not in text

@@ -205,6 +205,99 @@ def test_pallas_tile_param_matches_dense(tile):
 
 
 # ---------------------------------------------------------------------------
+# the jnp backend's two forms: separable at threshold 0, masked above it
+# ---------------------------------------------------------------------------
+
+
+def _dense_product(ab, am, bb, bm):
+    """float64 (mA ⊙ A)(mB ⊙ B) as dense matrices."""
+    def dense(blocks, mask):
+        x = np.asarray(blocks, np.float64) * np.asarray(mask)[:, :, None, None]
+        r, c, br, bc = x.shape
+        return x.transpose(0, 2, 1, 3).reshape(r * br, c * bc)
+
+    return dense(ab, am) @ dense(bb, bm)
+
+
+def _separable_case(case):
+    shape = (8, 4, 8) if case == "rectangular" else (8, 8, 8)
+    dtype = jnp.bfloat16 if case == "bf16" else jnp.float32
+    ab, am, an, bb, bm, bn = _mats(31, 5, 6, 4, *shape, 0.6, dtype)
+    # an empty block row of A, an empty block column of B and an empty k
+    am = am.at[1].set(False).at[:, 2].set(False)
+    bm = bm.at[:, 3].set(False).at[2].set(False)
+    if case == "junk_under_mask":
+        # nonzero data (and norms) under every false mask entry
+        k1, k2 = jax.random.split(jax.random.key(32))
+        ab = jnp.where(am[:, :, None, None], ab,
+                       jax.random.normal(k1, ab.shape).astype(dtype))
+        bb = jnp.where(bm[:, :, None, None], bb,
+                       jax.random.normal(k2, bb.shape).astype(dtype))
+        an, bn = an + 1.0, bn + 1.0
+    return ab, am, an, bb, bm, bn
+
+
+@pytest.mark.parametrize(
+    "case", ["empty_rows_cols", "junk_under_mask", "rectangular", "bf16"])
+def test_separable_form_matches_masked_oracle(case):
+    """At threshold 0 the jnp backend contracts (mA ⊙ A)(mB ⊙ B) without
+    the filter cube: it equals the cube-weighted einsum and the dense
+    product, ignores data under false mask entries, and its C mask is
+    exactly any_k of the filter cube."""
+    args = _separable_case(case)
+    ab, am, an, bb, bm, bn = args
+    fn = jax.jit(lambda *xs: local_filtered_mm(*xs, backend="jnp"))
+    assert "spgemm.local/separable" in fn.lower(*args).as_text(
+        debug_info=True)
+    got, got_m = fn(*args)
+    ok = pair_filter(am, an, bm, bn, 0.0)
+    assert got.dtype == ab.dtype
+    np.testing.assert_array_equal(np.asarray(got_m),
+                                  np.asarray(jnp.any(ok, axis=1)))
+    tol = _DTYPE_TOL[jnp.dtype(ab.dtype).name]
+    want = kref.block_spgemm_ref(ab, bb, ok)  # the cube-weighted einsum
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    ni, nj, bs_r, bs_c = got.shape
+    dense = np.asarray(got, np.float64).transpose(0, 2, 1, 3).reshape(
+        ni * bs_r, nj * bs_c)
+    np.testing.assert_allclose(dense, _dense_product(ab, am, bb, bm),
+                               rtol=tol, atol=tol)
+    # empty rows and columns of C come out zero, not merely unmasked
+    assert float(jnp.abs(got[1]).max()) == 0.0
+    assert float(jnp.abs(got[:, 3]).max()) == 0.0
+
+
+def test_positive_threshold_still_filters_per_triple():
+    """Above 0 the filter couples i, k and j: the one product whose norm
+    product falls just under the threshold is dropped, while the same A
+    block times another B block, and another A block times the same B
+    block, are kept."""
+    ab, am, _, bb, bm, _ = _mats(33, 2, 2, 2, 4, 4, 4, 1.0, jnp.float32)
+    am, bm = jnp.ones((2, 2), bool), jnp.ones((2, 2), bool)
+    thr = 1.0
+    an = jnp.array([[2.0, 0.5], [2.0, 2.0]])
+    bn = jnp.array([[1.0, 1.0], [1.99, 4.0]])  # 0.5 * 1.99 = 0.995 < 1
+    fn = jax.jit(lambda *xs: local_filtered_mm(*xs, threshold=thr,
+                                               backend="jnp"))
+    lowered = fn.lower(ab, am, an, bb, bm, bn)
+    assert "spgemm.local/separable" not in lowered.as_text(debug_info=True)
+    got, got_m = fn(ab, am, an, bb, bm, bn)
+    ok = np.ones((2, 2, 2), bool)
+    ok[0, 1, 0] = False
+    np.testing.assert_array_equal(
+        np.asarray(pair_filter(am, an, bm, bn, thr)), ok)
+    want = np.einsum("ikj,ikab,kjbc->ijac", ok, np.asarray(ab, np.float64),
+                     np.asarray(bb, np.float64))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+    assert bool(jnp.all(got_m))
+    # the dropped product is not a rounding-level term
+    kept = np.einsum("ab,bc->ac", np.asarray(ab[0, 1]), np.asarray(bb[1, 0]))
+    assert np.abs(kept).max() > 0.1
+
+
+# ---------------------------------------------------------------------------
 # compaction machinery
 # ---------------------------------------------------------------------------
 

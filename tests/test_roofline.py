@@ -240,6 +240,26 @@ def test_spgemm_stacks_flops_match_cost_analysis():
     assert measured < 0.5 * measured_dense
 
 
+def test_separable_local_stage_prices_the_full_cube():
+    """At threshold 0 the jnp backend contracts the masked blocks without
+    the filter cube, and still multiplies every block of it: cost_analysis
+    prices the full cube, the yardstick of ``local_mm_roofline``."""
+    from repro.core.bsm import random_bsm
+    from repro.core.local_mm import local_filtered_mm
+    from repro.roofline import spgemm_dense_flops
+
+    nb, bs = 12, 8
+    a = random_bsm(jax.random.key(52), nb, bs, occupancy=0.15)
+    b = random_bsm(jax.random.key(53), nb, bs, occupancy=0.15)
+    fn = jax.jit(lambda *xs: local_filtered_mm(*xs, backend="jnp"))
+    lowered = fn.lower(a.blocks, a.mask, a.norms, b.blocks, b.mask, b.norms)
+    assert "spgemm.local/separable" in lowered.as_text(debug_info=True)
+    measured = xla_cost_analysis(lowered.compile())["flops"]
+    dense = spgemm_dense_flops(nb, nb, nb, bs, bs, bs)
+    assert measured >= dense
+    assert measured == pytest.approx(dense, rel=0.25)
+
+
 def test_local_stage_cost_dtype_and_tile_aware():
     """Satellite: the dtype/tile-aware local cost model vs cost_analysis.
 
